@@ -1,4 +1,4 @@
-"""StableLM — dense GQA decoder. [hf:stabilityai/stablelm-2-1_6b family]."""
+"""StableLM-3B-4E1T — dense decoder. [hf:stabilityai/stablelm-3b-4e1t]."""
 import dataclasses
 from repro.configs.base import ModelConfig
 
@@ -6,7 +6,7 @@ CONFIG = ModelConfig(
     name="stablelm-3b", arch_type="dense",
     num_layers=32, d_model=2560, num_heads=32, num_kv_heads=32,
     d_ff=6912, vocab_size=50304,
-    source="hf:stabilityai/stablelm-2-1_6b",
+    source="hf:stabilityai/stablelm-3b-4e1t",
 )
 
 def smoke_config() -> ModelConfig:

@@ -111,9 +111,11 @@ def block_gather_quant_layers(pools, indices, *, interpret: bool = True):
         scale = jnp.maximum(amax / 127.0, 1e-8)
         q = jnp.clip(jnp.round(x / scale[None, :, None]), -127, 127)
         q_ref[0, 0] = q.astype(jnp.int8)
-        s_ref[0, 0] = scale
+        s_ref[0, 0, 0] = scale
 
-    return pl.pallas_call(
+    # scales carry a unit axis so each step's (1, Hkv) tile spans the
+    # array's two trailing dims, as the TPU block-shape rule requires
+    qs, scales = pl.pallas_call(
         kernel,
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=1,
@@ -122,13 +124,14 @@ def block_gather_quant_layers(pools, indices, *, interpret: bool = True):
                                    lambda l, i, idx: (l, idx[i], 0, 0, 0))],
             out_specs=[pl.BlockSpec((1, 1, bs, hkv, d),
                                     lambda l, i, idx: (l, i, 0, 0, 0)),
-                       pl.BlockSpec((1, 1, hkv),
-                                    lambda l, i, idx: (l, i, 0))],
+                       pl.BlockSpec((1, 1, 1, hkv),
+                                    lambda l, i, idx: (l, i, 0, 0))],
         ),
         out_shape=[jax.ShapeDtypeStruct((nl, m, bs, hkv, d), jnp.int8),
-                   jax.ShapeDtypeStruct((nl, m, hkv), jnp.float32)],
+                   jax.ShapeDtypeStruct((nl, m, 1, hkv), jnp.float32)],
         interpret=interpret,
     )(indices, pools)
+    return qs, scales[:, :, 0]
 
 
 def block_scatter_dequant_layers(pools, indices, staging, scales,
@@ -146,7 +149,7 @@ def block_scatter_dequant_layers(pools, indices, staging, scales,
     def kernel(idx_ref, staging_ref, scales_ref, pools_in_ref,
                pools_out_ref):
         q = staging_ref[0, 0].astype(jnp.float32)      # (bs, Hkv, D)
-        s = scales_ref[0, 0]                           # (Hkv,)
+        s = scales_ref[0, 0, 0]                        # (Hkv,)
         pools_out_ref[0, 0] = (q * s[None, :, None]).astype(
             pools_out_ref.dtype)
 
@@ -158,8 +161,8 @@ def block_scatter_dequant_layers(pools, indices, staging, scales,
             in_specs=[
                 pl.BlockSpec((1, 1, bs, hkv, d),
                              lambda l, i, idx: (l, i, 0, 0, 0)),
-                pl.BlockSpec((1, 1, hkv),
-                             lambda l, i, idx: (l, i, 0)),
+                pl.BlockSpec((1, 1, 1, hkv),
+                             lambda l, i, idx: (l, i, 0, 0)),
                 pl.BlockSpec((1, 1, bs, hkv, d),
                              lambda l, i, idx: (l, idx[i], 0, 0, 0)),
             ],
@@ -169,7 +172,7 @@ def block_scatter_dequant_layers(pools, indices, staging, scales,
         out_shape=jax.ShapeDtypeStruct(pools.shape, pools.dtype),
         input_output_aliases={3: 0},
         interpret=interpret,
-    )(indices, staging, scales, pools)
+    )(indices, staging, scales[:, :, None], pools)
 
 
 def block_scatter(pages, indices, staging, *, interpret: bool = True):

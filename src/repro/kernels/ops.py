@@ -1,14 +1,14 @@
 """Public jit'd wrappers for the Pallas kernels.
 
-``interpret`` defaults to True in this CPU container (Pallas interpret mode
-executes the kernel body in Python for correctness validation); on a real
-TPU deployment set ``repro.kernels.ops.INTERPRET = False`` (or the
-``REPRO_PALLAS_COMPILE=1`` env var) and the same calls compile to Mosaic.
+The kernel mode follows the backend the call is traced for: on a TPU
+every kernel compiles to Mosaic (gridded variants); on the CPU it runs in
+Pallas interpret mode (flat variants), which is how the tests validate
+the kernels against the ``ref`` oracles. Any other backend is refused —
+there is no fallback path.
 """
 from __future__ import annotations
 
 import functools
-import os
 
 import jax
 import jax.numpy as jnp
@@ -20,46 +20,55 @@ from repro.kernels import paged_prefill as _pp
 from repro.kernels import ssd_scan as _ssd
 from repro.kernels import swa_attention as _swa
 
-INTERPRET = os.environ.get("REPRO_PALLAS_COMPILE", "0") != "1"
+
+def _interpret() -> bool:
+    """Pallas interpret mode for the current backend, decided when a
+    wrapper is traced (never at import)."""
+    backend = jax.default_backend()
+    if backend == "cpu":
+        return True
+    if backend == "tpu":
+        return False
+    raise RuntimeError(f"no Pallas kernel path for backend {backend!r}")
 
 
 @functools.partial(jax.jit, static_argnames=())
 def paged_attention(q, k_pages, v_pages, block_tables, context_lens):
     """Decode attention over the paged KV pool. See kernel docstring."""
     return _pa.paged_attention(q, k_pages, v_pages, block_tables,
-                               context_lens, interpret=INTERPRET)
+                               context_lens, interpret=_interpret())
 
 
 @jax.jit
 def paged_prefill_attention(q, k_pages, v_pages, block_tables, q_pos):
     """Chunked suffix-prefill attention over the paged KV pool."""
     return _pp.paged_prefill_attention(q, k_pages, v_pages, block_tables,
-                                       q_pos, interpret=INTERPRET)
+                                       q_pos, interpret=_interpret())
 
 
 @jax.jit
 def block_gather(pages, indices):
     """Gather pool blocks into a contiguous staging buffer (offload)."""
-    return _bc.block_gather(pages, indices, interpret=INTERPRET)
+    return _bc.block_gather(pages, indices, interpret=_interpret())
 
 
 @jax.jit
 def block_scatter(pages, indices, staging):
     """Scatter a staging buffer into pool blocks (upload), in place."""
-    return _bc.block_scatter(pages, indices, staging, interpret=INTERPRET)
+    return _bc.block_scatter(pages, indices, staging, interpret=_interpret())
 
 
 @jax.jit
 def block_gather_layers(pools, indices):
     """Gather blocks across every layer at once (offload staging)."""
-    return _bc.block_gather_layers(pools, indices, interpret=INTERPRET)
+    return _bc.block_gather_layers(pools, indices, interpret=_interpret())
 
 
 @jax.jit
 def block_scatter_layers(pools, indices, staging):
     """Scatter a staging buffer into pool blocks across every layer."""
     return _bc.block_scatter_layers(pools, indices, staging,
-                                    interpret=INTERPRET)
+                                    interpret=_interpret())
 
 
 @functools.partial(jax.jit, static_argnames=())
@@ -68,7 +77,7 @@ def paged_attention_quant(q, k_pages, v_pages, k_scale, v_scale,
     """Decode attention over an int8-quantized pool (dequant fused)."""
     return _pa.paged_attention_quant(q, k_pages, v_pages, k_scale, v_scale,
                                      block_tables, context_lens,
-                                     interpret=INTERPRET)
+                                     interpret=_interpret())
 
 
 @jax.jit
@@ -77,40 +86,40 @@ def paged_prefill_attention_quant(q, k_pages, v_pages, k_scale, v_scale,
     """Chunked suffix-prefill attention over an int8-quantized pool."""
     return _pp.paged_prefill_attention_quant(q, k_pages, v_pages, k_scale,
                                              v_scale, block_tables, q_pos,
-                                             interpret=INTERPRET)
+                                             interpret=_interpret())
 
 
 @jax.jit
 def kv_block_quant(blocks):
     """Quantize staged KV blocks to int8 + per-(block, kv-head) scales."""
-    return _kw.kv_block_quant(blocks, interpret=INTERPRET)
+    return _kw.kv_block_quant(blocks, interpret=_interpret())
 
 
 @functools.partial(jax.jit, static_argnames=("out_dtype",))
 def kv_block_dequant(q, scales, out_dtype=jnp.float32):
     """Dequantize int8 KV blocks back to ``out_dtype``."""
-    return _kw.kv_block_dequant(q, scales, out_dtype, interpret=INTERPRET)
+    return _kw.kv_block_dequant(q, scales, out_dtype, interpret=_interpret())
 
 
 @jax.jit
 def block_gather_quant_layers(pools, indices):
     """Fused all-layer gather + int8 quantize (quantize-on-offload)."""
     return _bc.block_gather_quant_layers(pools, indices,
-                                         interpret=INTERPRET)
+                                         interpret=_interpret())
 
 
 @jax.jit
 def block_scatter_dequant_layers(pools, indices, staging, scales):
     """Fused dequantize + all-layer scatter (promotion/pull delivery)."""
     return _bc.block_scatter_dequant_layers(pools, indices, staging,
-                                            scales, interpret=INTERPRET)
+                                            scales, interpret=_interpret())
 
 
 @jax.jit
 def kv_token_write(k_pages, v_pages, k_new, v_new, slots):
     """Batched one-token-per-sequence KV write into the paged pool."""
     return _kw.kv_token_write(k_pages, v_pages, k_new, v_new, slots,
-                              interpret=INTERPRET)
+                              interpret=_interpret())
 
 
 @jax.jit
@@ -121,13 +130,13 @@ def kv_chunk_write(k_pages, v_pages, k_new, v_new, wpages, wstart, wcount):
     steps; here each live page is one grid step. Flat one-shot scatter
     under the CPU interpreter."""
     return _kw.kv_chunk_write(k_pages, v_pages, k_new, v_new, wpages,
-                              wstart, wcount, interpret=INTERPRET)
+                              wstart, wcount, interpret=_interpret())
 
 
 @functools.partial(jax.jit, static_argnames=("chunk",))
 def ssd_scan(x, dt, a, b, c, chunk: int = 64):
     """Chunked Mamba2 SSD scan; returns (y, final_state)."""
-    return _ssd.ssd_scan(x, dt, a, b, c, chunk=chunk, interpret=INTERPRET)
+    return _ssd.ssd_scan(x, dt, a, b, c, chunk=chunk, interpret=_interpret())
 
 
 @functools.partial(jax.jit, static_argnames=("window", "q_block", "kv_block"))
@@ -135,4 +144,4 @@ def swa_attention(q, k, v, window: int, q_block: int = 128,
                   kv_block: int = 128):
     """Sliding-window causal flash attention (prefill)."""
     return _swa.swa_attention(q, k, v, window, q_block, kv_block,
-                              interpret=INTERPRET)
+                              interpret=_interpret())

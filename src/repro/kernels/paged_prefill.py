@@ -14,7 +14,7 @@ Mirrors the paged-decode kernel's structure (PR 1):
  * gridded TPU path — grid = (batch, page), block-table entries scalar-
    prefetched so the page index map can gather; per-batch flash
    accumulators (m, l, acc) live in VMEM scratch across page iterations.
-   Each step does the full (Hkv, C, G) x (bs) score block, so chunked
+   Each step does the full (Hkv, C*G) x (bs) score block, so chunked
    prefill gets MXU-sized matmuls instead of decode's single-row GEMVs;
  * flat CPU path — the batch/page loops collapse into in-kernel
    ``fori_loop``s over dynamic ref slices (interpret mode pays O(full
@@ -45,6 +45,10 @@ def _kernel(block_tables_ref,                       # scalar prefetch
             o_ref,                                  # output block
             m_scr, l_scr, acc_scr,                  # VMEM scratch
             *, block_size: int, num_pages: int):
+    """Query rows are (chunk token, group head) pairs flattened to R = C*G,
+    so every operand is 3-D like the decode kernel's and the per-row
+    position arrives as an (R, 1) column that broadcasts along the lanes
+    (Mosaic cannot move a lane vector onto the sublane axis)."""
     p = pl.program_id(1)
 
     @pl.when(p == 0)
@@ -53,22 +57,22 @@ def _kernel(block_tables_ref,                       # scalar prefetch
         l_scr[...] = jnp.zeros_like(l_scr)
         acc_scr[...] = jnp.zeros_like(acc_scr)
 
-    qp = qpos_ref[0]                                   # (C,) int32
-    q = q_ref[0].astype(jnp.float32)                   # (Hkv, C, G, D)
+    qp = qpos_ref[0]                                   # (R, 1) int32
+    q = q_ref[0].astype(jnp.float32)                   # (Hkv, R, D)
     k = k_ref[0].astype(jnp.float32)                   # (bs, Hkv, D)
     v = v_ref[0].astype(jnp.float32)
     scale = 1.0 / jnp.sqrt(jnp.float32(q.shape[-1]))
 
-    scores = jax.lax.dot_general(                      # (Hkv, C, G, bs)
-        q, k, (((3,), (2,)), ((0,), (1,))),
+    scores = jax.lax.dot_general(                      # (Hkv, R, bs)
+        q, k, (((2,), (2,)), ((0,), (1,))),
         preferred_element_type=jnp.float32) * scale
     kv_pos = p * block_size + jax.lax.broadcasted_iota(
-        jnp.int32, (1, 1, 1, block_size), 3)
-    valid = kv_pos <= qp[None, :, None, None]          # (1, C, 1, bs)
+        jnp.int32, (1, block_size), 1)
+    valid = (kv_pos <= qp)[None]                       # (1, R, bs)
     scores = jnp.where(valid, scores, NEG_INF)
 
     # ---- online softmax (flash) update ----
-    m_prev = m_scr[...]                                # (Hkv, C, G, 1)
+    m_prev = m_scr[...]                                # (Hkv, R, 1)
     l_prev = l_scr[...]
     m_cur = jnp.max(scores, axis=-1, keepdims=True)
     m_new = jnp.maximum(m_prev, m_cur)
@@ -76,7 +80,7 @@ def _kernel(block_tables_ref,                       # scalar prefetch
     alpha = jnp.exp(m_prev - m_new)
     l_new = l_prev * alpha + probs.sum(axis=-1, keepdims=True)
     acc_scr[...] = acc_scr[...] * alpha + jax.lax.dot_general(
-        probs, v, (((3,), (0,)), ((0,), (1,))),        # (Hkv, C, G, D)
+        probs, v, (((2,), (0,)), ((0,), (1,))),        # (Hkv, R, D)
         preferred_element_type=jnp.float32)
     m_scr[...] = m_new
     l_scr[...] = l_new
@@ -314,6 +318,7 @@ def paged_prefill_attention(q, k_pages, v_pages, block_tables, q_pos,
         )(block_tables, q_pos, qt, k_pages, v_pages)
         return out.transpose(0, 2, 1, 3, 4).reshape(b, c, h, d)
 
+    r = c * g
     kernel = functools.partial(_kernel, block_size=bs, num_pages=p)
     out = pl.pallas_call(
         kernel,
@@ -321,23 +326,25 @@ def paged_prefill_attention(q, k_pages, v_pages, block_tables, q_pos,
             num_scalar_prefetch=1,
             grid=(b, p),
             in_specs=[
-                pl.BlockSpec((1, c), lambda b_, p_, bt: (b_, 0)),
-                pl.BlockSpec((1, hkv, c, g, d),
-                             lambda b_, p_, bt: (b_, 0, 0, 0, 0)),
+                pl.BlockSpec((1, r, 1), lambda b_, p_, bt: (b_, 0, 0)),
+                pl.BlockSpec((1, hkv, r, d),
+                             lambda b_, p_, bt: (b_, 0, 0, 0)),
                 pl.BlockSpec((1, bs, hkv, d),
                              lambda b_, p_, bt: (bt[b_, p_], 0, 0, 0)),
                 pl.BlockSpec((1, bs, hkv, d),
                              lambda b_, p_, bt: (bt[b_, p_], 0, 0, 0)),
             ],
-            out_specs=pl.BlockSpec((1, hkv, c, g, d),
-                                   lambda b_, p_, bt: (b_, 0, 0, 0, 0)),
+            out_specs=pl.BlockSpec((1, hkv, r, d),
+                                   lambda b_, p_, bt: (b_, 0, 0, 0)),
             scratch_shapes=[
-                pltpu.VMEM((hkv, c, g, 1), jnp.float32),
-                pltpu.VMEM((hkv, c, g, 1), jnp.float32),
-                pltpu.VMEM((hkv, c, g, d), jnp.float32),
+                pltpu.VMEM((hkv, r, 1), jnp.float32),
+                pltpu.VMEM((hkv, r, 1), jnp.float32),
+                pltpu.VMEM((hkv, r, d), jnp.float32),
             ],
         ),
-        out_shape=jax.ShapeDtypeStruct((b, hkv, c, g, d), q.dtype),
+        out_shape=jax.ShapeDtypeStruct((b, hkv, r, d), q.dtype),
         interpret=interpret,
-    )(block_tables, q_pos, qt, k_pages, v_pages)
-    return out.transpose(0, 2, 1, 3, 4).reshape(b, c, h, d)
+    )(block_tables, jnp.repeat(q_pos, g, axis=1)[:, :, None],
+      qt.reshape(b, hkv, r, d), k_pages, v_pages)
+    return out.reshape(b, hkv, c, g, d).transpose(0, 2, 1, 3, 4).reshape(
+        b, c, h, d)

@@ -60,7 +60,7 @@ from typing import Callable, Dict, List, Optional
 from repro.core.engine import Engine, EngineConfig
 from repro.core.graph import AppGraph, FuncNode
 from repro.launch.response_cache import ResponseCache, request_key
-from repro.launch.serve import MCPFrontend
+from repro.launch.serve import MCPFrontend, use_compile_cache
 
 _REASONS = {200: "OK", 400: "Bad Request", 404: "Not Found",
             405: "Method Not Allowed", 429: "Too Many Requests",
@@ -411,7 +411,10 @@ class HttpServer:
         self._server: Optional[asyncio.base_events.Server] = None
         self._loop: Optional[asyncio.AbstractEventLoop] = None
         self._thread: Optional[threading.Thread] = None
-        self._stopped = asyncio.Event()
+        self._pump_task: Optional[asyncio.Task] = None
+        # the exception that ended the pump: the server stops, every
+        # waiting client gets a 500, and the owner's thread re-raises it
+        self.error: Optional[BaseException] = None
 
     # ------------------------------------------------------------ pump / wake
     def _notify_finish(self, gen: GenRequest) -> None:
@@ -429,6 +432,26 @@ class HttpServer:
     def _kick(self) -> None:
         if self._wake is not None:
             self._wake.set()
+
+    def _pump_done(self, task: asyncio.Task) -> None:
+        """The pump only ends by failing (or by cancellation at stop):
+        record the exception, stop accepting, and release every waiting
+        handler so no client waits out its socket timeout."""
+        if task.cancelled() or task.exception() is None:
+            return
+        self.error = task.exception()
+        self._server.close()
+        for evs in self._waiters.values():
+            for ev in evs:
+                ev.set()
+        self._waiters.clear()
+        for q in self._streams.values():
+            q.put_nowait(("error", None))
+
+    def _fail(self, writer: asyncio.StreamWriter) -> None:
+        self._send(writer, 500, {"ok": False, "error": "engine failed: "
+                                 f"{type(self.error).__name__}: "
+                                 f"{self.error}"})
 
     def _sync_idle_clock(self) -> None:
         """Advance the engine's virtual clock across a wall-clock idle
@@ -639,6 +662,9 @@ class HttpServer:
 
     async def _generate(self, payload: dict, params: dict,
                         writer: asyncio.StreamWriter) -> None:
+        if self.error is not None:
+            self._fail(writer)
+            return
         stream = payload.pop("stream", params.get("stream") == "1")
         async_ = payload.pop("async", params.get("async") == "1")
         try:
@@ -664,6 +690,9 @@ class HttpServer:
         ev = asyncio.Event()
         self._waiters.setdefault(gen.gid, []).append(ev)
         await ev.wait()
+        if self.error is not None:
+            self._fail(writer)
+            return
         self._send(writer, 200, dict(gen.result, ttft=gen.ttft(),
                                      latency=gen.latency()))
 
@@ -696,6 +725,14 @@ class HttpServer:
                                             "done": False}))
                         sent = len(toks)
                         await writer.drain()
+                elif kind == "error":
+                    writer.write(chunk({"id": gen.gid, "done": True,
+                                        "ok": False,
+                                        "error": f"engine failed: "
+                                                 f"{self.error}"}))
+                    writer.write(b"0\r\n\r\n")
+                    await writer.drain()
+                    return
                 else:   # done
                     toks = gen.result.get("tokens", [])
                     writer.write(chunk({"id": gen.gid,
@@ -723,11 +760,22 @@ class HttpServer:
                                                   self.port)
         self.port = self._server.sockets[0].getsockname()[1]
         self._pump_task = asyncio.ensure_future(self._pump())
+        self._pump_task.add_done_callback(self._pump_done)
 
     async def serve_forever(self) -> None:
+        """Serve until cancelled; raises the pump's exception if the
+        engine fails."""
         await self.start()
         async with self._server:
-            await self._server.serve_forever()
+            serving = asyncio.ensure_future(self._server.serve_forever())
+            try:
+                await asyncio.wait({serving, self._pump_task},
+                                   return_when=asyncio.FIRST_COMPLETED)
+            finally:
+                serving.cancel()
+                self._pump_task.cancel()
+        if self.error is not None:
+            raise self.error
 
     # ---- background-thread harness (tests / self-test) ----------------------
     def start_background(self) -> int:
@@ -743,20 +791,28 @@ class HttpServer:
         self._thread.start()
         if not ready.wait(timeout=30):
             raise RuntimeError("HTTP server failed to start")
+        if self.error is not None:
+            raise self.error
         return self.port
 
     async def _bg_main(self, ready: threading.Event) -> None:
         await self.start()
         self._stop_ev = asyncio.Event()
         ready.set()
-        await self._stop_ev.wait()
+        stop = asyncio.ensure_future(self._stop_ev.wait())
+        await asyncio.wait({stop, self._pump_task},
+                           return_when=asyncio.FIRST_COMPLETED)
+        stop.cancel()
         self._pump_task.cancel()
         self._server.close()
         await self._server.wait_closed()
 
     def _threadsafe(self, fn) -> None:
         if self._loop is not None:
-            self._loop.call_soon_threadsafe(fn)
+            try:
+                self._loop.call_soon_threadsafe(fn)
+            except RuntimeError:
+                pass    # the loop already ended (the pump failed)
 
     def pause(self) -> None:
         """Freeze the engine pump (tests: make admission state
@@ -770,9 +826,13 @@ class HttpServer:
         self._threadsafe(_go)
 
     def stop(self) -> None:
+        """Stop the background server; re-raises the pump's exception
+        if the engine failed while serving."""
         self._threadsafe(lambda: self._stop_ev.set())
         if self._thread is not None:
             self._thread.join(timeout=30)
+        if self.error is not None:
+            raise self.error
 
 
 # ---------------------------------------------------------------------------
@@ -855,6 +915,7 @@ def main() -> None:
                     help="boot on an ephemeral port, run a scripted "
                          "client burst, print the report JSON, exit")
     args = ap.parse_args()
+    use_compile_cache()
     if args.selftest:
         rep = _selftest()
         print(json.dumps(rep, indent=1, default=str))

@@ -13,22 +13,117 @@ error* — it is reported back, logged, and counted in
 report instead of silently degrading the schedule.
 
     PYTHONPATH=src python -m repro.launch.serve --mode tokencake \
-        --apps 20 --qps 1.0 [--real-compute] [--prefetch]
+        --apps 20 --qps 1.0 [--arch stablelm_3b] [--prefetch]
+
+``--arch NAME`` serves the named config at its published widths through
+``JaxBackend`` on the TPU v5e platform model (:func:`build_model_engine`);
+without it the engine runs the pure simulation.
 """
 from __future__ import annotations
 
 import argparse
 import json
 import logging
+import os
+from pathlib import Path
 
-from repro.configs.base import get_smoke_config
-from repro.core.costmodel import PLATFORMS, A100_PCIE
+import jax
+import jax.numpy as jnp
+
+from repro.configs.base import get_config
+from repro.core.costmodel import PLATFORMS, TPU_V5E
 from repro.core.engine import Engine, EngineConfig
 from repro.core.request import ReqState
 from repro.core.temporal import TemporalConfig
 from repro.data.workloads import build_workload
+from repro.models import model as M
 
 log = logging.getLogger("repro.serve")
+
+REPO_ROOT = Path(__file__).resolve().parents[3]
+
+# Device memory of a served model: the parameters, the K and V pools, and
+# the paged steps' temporaries, which hold about one more copy of both
+# pools (the compiler relays out each layer's pool slice around every
+# kernel call). A sixteenth of the device's limit stays free for
+# activations, logits and migration staging.
+HBM_MARGIN = 1 / 16
+# The host tier holds every device block twice over, within this bound.
+HOST_TIER_BYTES = 8 << 30
+
+
+def use_compile_cache() -> None:
+    """Persistent compile cache for the entry points. JAX reads
+    ``JAX_COMPILATION_CACHE_DIR`` itself when it is set; otherwise the
+    cache lives at a fixed, git-ignored directory of the checkout, so the
+    next run from the same checkout finds it."""
+    if not os.environ.get("JAX_COMPILATION_CACHE_DIR"):
+        jax.config.update("jax_compilation_cache_dir",
+                          str(REPO_ROOT / ".jax_cache"))
+
+
+def hbm_limit() -> int:
+    """Bytes the first device lets one process allocate."""
+    dev = jax.devices()[0]
+    stats = dev.memory_stats() or {}
+    if "bytes_limit" not in stats:
+        raise RuntimeError(f"{dev} reports no memory limit: a model is "
+                           "served only on an accelerator")
+    return stats["bytes_limit"]
+
+
+def _block_bytes(cfg, block_tokens: int) -> int:
+    """Unpadded bytes of one block of one K or V pool, all layers."""
+    return (cfg.num_layers * block_tokens * cfg.num_kv_heads * cfg.head_dim
+            * jnp.dtype(M._dtype(cfg)).itemsize)
+
+
+def _pool_bytes(cfg, n_blocks: int, block_tokens: int) -> int:
+    """Device bytes of one K or V pool of ``n_blocks`` plus the scratch
+    block, as the backend lays it out (a TPU pads tiled dimensions)."""
+    shape = (cfg.num_layers, n_blocks + 1, block_tokens, cfg.num_kv_heads,
+             cfg.head_dim)
+    zeros = jax.jit(lambda: jnp.zeros(shape, M._dtype(cfg)))
+    return zeros.lower().compile().memory_analysis().output_size_in_bytes
+
+
+def pool_blocks(cfg, block_tokens: int, limit: int) -> int:
+    """Largest device pool whose K and V pools, twice over (resident plus
+    the steps' temporaries), fit in ``limit`` after the parameters and
+    the margin."""
+    params = sum(x.size * x.dtype.itemsize
+                 for x in jax.tree.leaves(M.param_specs(cfg)))
+    budget = limit * (1 - HBM_MARGIN) - params
+    lo, hi = 0, max(int(budget // (4 * _block_bytes(cfg, block_tokens))), 0)
+    while lo < hi:                               # bytes grow with blocks
+        mid = (lo + hi + 1) // 2
+        if 4 * _pool_bytes(cfg, mid, block_tokens) <= budget:
+            lo = mid
+        else:
+            hi = mid - 1
+    if lo == 0:
+        raise RuntimeError(f"{cfg.name}: {params} parameter bytes leave no "
+                           f"room for a KV pool in {limit} device bytes")
+    return lo
+
+
+def build_model_engine(cfg, mode: str = "tokencake", seed: int = 0,
+                       **engine_kw) -> Engine:
+    """Engine serving ``cfg`` through ``JaxBackend`` (weights drawn from
+    ``seed``) on the TPU v5e platform model. The device pool is sized
+    from the device's memory limit (:func:`pool_blocks`) and the host
+    tier from it (:data:`HOST_TIER_BYTES`); both override ``engine_kw``."""
+    from repro.core.backend import JaxBackend
+
+    plat = TPU_V5E
+    gpu = pool_blocks(cfg, plat.block_tokens, hbm_limit())
+    host = min(2 * gpu,
+               HOST_TIER_BYTES // (2 * _block_bytes(cfg, plat.block_tokens)))
+    kw = dict(dict(max_running=64), **engine_kw)
+    kw.update(gpu_blocks=gpu, host_blocks=host)
+    ecfg = EngineConfig.preset(mode, **kw)
+    backend = JaxBackend(cfg, ecfg, plat, key=jax.random.PRNGKey(seed))
+    return Engine(ecfg, plat, backend=backend)
 
 
 class MCPFrontend:
@@ -180,8 +275,11 @@ def main():
     ap.add_argument("--blocks", type=int, default=640)
     ap.add_argument("--platform", default="a100_pcie_qwen14b",
                     choices=list(PLATFORMS))
-    ap.add_argument("--real-compute", action="store_true",
-                    help="tiny model + real paged KV + Pallas kernels")
+    ap.add_argument("--arch", default=None, metavar="NAME",
+                    help="serve this config (e.g. stablelm_3b) at its "
+                         "published widths through JaxBackend on the "
+                         "TPU v5e platform model; pool sizes are derived "
+                         "from device memory (--blocks/--platform unused)")
     ap.add_argument("--prefetch", action="store_true",
                     help="host-tier promotion + workflow-aware KV prefetch")
     ap.add_argument("--sessions", action="store_true",
@@ -203,6 +301,9 @@ def main():
                          "(see docs/SERVING_API.md)")
     args = ap.parse_args()
 
+    if args.arch is not None and args.replicas > 1:
+        ap.error("--arch serves one replica (cluster replicas own no "
+                 "device)")
     plat = PLATFORMS[args.platform]
     kw = dict(gpu_blocks=args.blocks, max_running=64)
     if args.prefetch:
@@ -210,29 +311,32 @@ def main():
                   temporal=TemporalConfig(prefetch=True))
     if args.sessions:
         kw.update(sessions=True)
+    use_compile_cache()
     if args.http is not None:
         import asyncio
 
         from repro.launch.http_server import HttpServer
-        srv = HttpServer(port=args.http,
-                         engine_kw=dict(kw, continuous_batching=True))
+        kw.update(continuous_batching=True)
+        if args.arch is not None:
+            srv = HttpServer(port=args.http, engine=build_model_engine(
+                get_config(args.arch), **kw))
+        else:
+            srv = HttpServer(port=args.http, engine_kw=kw)
         log.info("serving on http://%s:%d", srv.host, args.http)
         asyncio.run(srv.serve_forever())
         return
     if args.replicas > 1:
         _serve_cluster(args, plat, kw)
         return
-    ecfg = EngineConfig.preset(args.mode, **kw)
-    backend = None
-    if args.real_compute:
-        from repro.core.backend import JaxBackend
-        backend = JaxBackend(get_smoke_config("glm4_9b"), ecfg, plat)
-    eng = Engine(ecfg, plat, backend=backend)
+    if args.arch is not None:
+        eng = build_model_engine(get_config(args.arch), args.mode, **kw)
+    else:
+        eng = Engine(EngineConfig.preset(args.mode, **kw), plat)
     front = MCPFrontend(eng)
 
     for t, g in build_workload(args.app, qps=args.qps, n_apps=args.apps,
                                seed=1):
-        if args.real_compute:
+        if args.arch is not None:    # real compute: cut the traffic
             for n in g.nodes.values():
                 n.prompt_len = min(n.prompt_len, 64)
                 n.decode_segments = [min(s, 16) for s in n.decode_segments]

@@ -336,6 +336,58 @@ def test_http_cache_flush(server):
     assert len(srv.front.cache) == 0
 
 
+# ------------------------------------------------------------ pump failure
+
+class _EngineFault(RuntimeError):
+    pass
+
+
+def _failing_step():
+    raise _EngineFault("kernel refused")
+
+
+@pytest.mark.parametrize("stream", [False, True])
+def test_pump_failure_reaches_clients_and_owner(stream):
+    """An engine step that raises stops the server: the waiting client
+    gets a 500 (or a final error chunk) at once instead of its socket
+    timeout, and ``stop`` re-raises the exception in the owner's
+    thread."""
+    srv = HttpServer(engine_kw=dict(gpu_blocks=64))
+    idle_step = srv.engine.step
+
+    def step():             # fails once the request reaches the engine
+        return _failing_step() if srv.engine.apps else idle_step()
+
+    srv.engine.step = step
+    port = srv.start_background()
+    t0 = time.monotonic()
+    c = http.client.HTTPConnection("127.0.0.1", port, timeout=30)
+    c.request("POST", "/generate" + ("?stream=1" if stream else ""),
+              json.dumps({"prompt": PROMPT, "max_tokens": 4}),
+              {"Content-Type": "application/json"})
+    r = c.getresponse()
+    body = r.read().decode()
+    c.close()
+    assert time.monotonic() - t0 < 10
+    if stream:
+        last = json.loads(body.splitlines()[-1])
+        assert last["ok"] is False and "kernel refused" in last["error"]
+    else:
+        assert r.status == 500 and "kernel refused" in json.loads(body)[
+            "error"]
+    with pytest.raises(_EngineFault):
+        srv.stop()
+
+
+def test_pump_failure_ends_serve_forever():
+    import asyncio
+
+    srv = HttpServer(engine_kw=dict(gpu_blocks=64))
+    srv.engine.step = _failing_step
+    with pytest.raises(_EngineFault):
+        asyncio.run(asyncio.wait_for(srv.serve_forever(), timeout=30))
+
+
 # ----------------------------------------- continuous batching equivalence
 
 def _sim_trace(continuous):
