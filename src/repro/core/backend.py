@@ -18,6 +18,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro import obs
 from repro.kvcache.paged import PagedKVCache
 from repro.models import model as M
 
@@ -95,29 +96,32 @@ def paged_prefill_chunks(cfg, params, cache, entries, chunk: int = 32):
     C = min(chunk, _bucket(s_max))
     pp = (C - 1) // bs + 2      # max pages a C-token window can straddle
     for c0 in range(0, s_max, C):
-        tok = np.zeros((bb, C), np.int32)
-        qpos = np.full((bb, C), -1, np.int32)
-        # write windows: destination pages in order + first in-page offset
-        # + valid count per row (scratch-page padded — see kv_chunk_write)
-        wpages = np.full((bb, pp), cache.scratch_block, np.int32)
-        wstart = np.zeros((bb,), np.int32)
-        wcount = np.zeros((bb,), np.int32)
-        for i, (blocks, toks, cached) in enumerate(entries):
-            n = min(len(suffix[i]) - c0, C)
-            if n <= 0:
-                continue
-            tok[i, :n] = suffix[i][c0:c0 + n]
-            qpos[i, :n] = cached + c0 + np.arange(n)
-            wpages[i], wstart[i] = cache.write_window(
-                blocks, cached + c0, n, pp)
-            wcount[i] = n
-        h, cache.k, cache.v = M.paged_prefill_step(
-            cfg, params, cache.k, cache.v, jnp.asarray(tok), jtables,
-            jnp.asarray(qpos), jnp.asarray(wpages), jnp.asarray(wstart),
-            jnp.asarray(wcount))
-        for i, s in enumerate(suffix):
-            if c0 <= len(s) - 1 < c0 + C:
-                last_h[i] = h[i, len(s) - 1 - c0]
+        with obs.span("backend.prefill_chunk", chunk=C) as sp:
+            tok = np.zeros((bb, C), np.int32)
+            qpos = np.full((bb, C), -1, np.int32)
+            # write windows: destination pages in order + first in-page
+            # offset + valid count per row (scratch-page padded — see
+            # kv_chunk_write)
+            wpages = np.full((bb, pp), cache.scratch_block, np.int32)
+            wstart = np.zeros((bb,), np.int32)
+            wcount = np.zeros((bb,), np.int32)
+            for i, (blocks, toks, cached) in enumerate(entries):
+                n = min(len(suffix[i]) - c0, C)
+                if n <= 0:
+                    continue
+                tok[i, :n] = suffix[i][c0:c0 + n]
+                qpos[i, :n] = cached + c0 + np.arange(n)
+                wpages[i], wstart[i] = cache.write_window(
+                    blocks, cached + c0, n, pp)
+                wcount[i] = n
+            sp.set(rows=int(np.count_nonzero(wcount)))
+            h, cache.k, cache.v = M.paged_prefill_step(
+                cfg, params, cache.k, cache.v, jnp.asarray(tok), jtables,
+                jnp.asarray(qpos), jnp.asarray(wpages), jnp.asarray(wstart),
+                jnp.asarray(wcount))
+            for i, s in enumerate(suffix):
+                if c0 <= len(s) - 1 < c0 + C:
+                    last_h[i] = h[i, len(s) - 1 - c0]
     return last_h
 
 
@@ -164,23 +168,24 @@ class JaxBackend:
 
     # -- engine hooks ----------------------------------------------------------
     def decode(self, reqs):
-        reqs = [r for r in reqs if r.num_gpu_blocks > 0]
-        if not reqs:
-            return
-        need = [r for r in reqs if self._needs_prefill(r)]
-        if need:
-            # batched suffix prefill serves archs whose layer body the
-            # paged scan reproduces exactly: dense, and moe now that
-            # padded rows are pinned to the sentinel expert (see
-            # decoder._paged_ffn / moe_ffn's pad_mask); window/ssm/
-            # cross-attn archs still take the per-request path
-            if self.cfg.arch_type in ("dense", "moe") \
-                    and self.cfg.sliding_window is None:
-                self._prefill_batch(need)
-            else:
-                for r in need:
-                    self._prefill_one(r)
-        self._decode_batch(reqs)
+        with obs.span("backend.decode"):
+            reqs = [r for r in reqs if r.num_gpu_blocks > 0]
+            if not reqs:
+                return
+            need = [r for r in reqs if self._needs_prefill(r)]
+            if need:
+                # batched suffix prefill serves archs whose layer body the
+                # paged scan reproduces exactly: dense, and moe now that
+                # padded rows are pinned to the sentinel expert (see
+                # decoder._paged_ffn / moe_ffn's pad_mask); window/ssm/
+                # cross-attn archs still take the per-request path
+                if self.cfg.arch_type in ("dense", "moe") \
+                        and self.cfg.sliding_window is None:
+                    self._prefill_batch(need)
+                else:
+                    for r in need:
+                        self._prefill_one(r)
+            self._decode_batch(reqs)
 
     def _needs_prefill(self, r) -> bool:
         sig = self._prefill_sig.get(r.rid)
@@ -191,20 +196,23 @@ class JaxBackend:
         Like copy_out/copy_in, this backend materializes device 0 only;
         TP mirror copies on other devices are accounting-only here."""
         if device == 0:
-            self.cache.copy_blocks(src, dst)
+            with obs.span("backend.copy_blocks", blocks=len(src)):
+                self.cache.copy_blocks(src, dst)
 
     def promote_blocks(self, host_blocks: List[int], gpu_blocks: List[int]):
         """Engine hook: host-tier prefix promotion — materialize the
         host-saved KV of a prefix hit into freshly allocated pool pages
         (all layers in one ``block_scatter_layers`` launch per tensor,
         the same H2D data plane request uploads ride)."""
-        self.cache.upload(host_blocks, gpu_blocks)
+        with obs.span("backend.promote_blocks", blocks=len(gpu_blocks)):
+            self.cache.upload(host_blocks, gpu_blocks)
 
     def offload_blocks(self, gpu_blocks: List[int], host_blocks: List[int]):
         """Engine hook: session-tier D2H save — move a finished turn's KV
         blocks (which no live request owns) to host pages, the same
         device→host data plane ``copy_out`` uses for stalled requests."""
-        self.cache.offload(gpu_blocks, host_blocks)
+        with obs.span("backend.offload_blocks", blocks=len(gpu_blocks)):
+            self.cache.offload(gpu_blocks, host_blocks)
 
     def generated_tokens(self, rid: str) -> Optional[List[int]]:
         """Decoded token ids so far — the serving front door's streaming
@@ -229,11 +237,15 @@ class JaxBackend:
         # only the private blocks move; the leading shared-prefix blocks
         # stay resident on device (the engine keeps them pinned and sized
         # host_blocks for the private count only)
-        self.cache.offload(req.gpu_blocks[req.shared_prefix_blocks:],
-                           req.host_blocks)
+        with obs.span("backend.copy_out", rid=req.rid,
+                      blocks=len(req.host_blocks)):
+            self.cache.offload(req.gpu_blocks[req.shared_prefix_blocks:],
+                               req.host_blocks)
 
     def copy_in(self, req):
-        self.cache.upload(req.host_blocks, req.reserved_upload_blocks)
+        with obs.span("backend.copy_in", rid=req.rid,
+                      blocks=len(req.host_blocks)):
+            self.cache.upload(req.host_blocks, req.reserved_upload_blocks)
         sig = self._prefill_sig.get(req.rid)
         if sig is not None:
             # post-upload table = resident shared-prefix blocks (which never
@@ -286,20 +298,24 @@ class JaxBackend:
         written — see ``paged_prefill_chunks``."""
         bs = self.block_tokens
         items = [(r, *self._prefill_tokens(r)) for r in reqs]
-        last_h = paged_prefill_chunks(
-            self.cfg, self.params, self.cache,
-            [(r.gpu_blocks, toks, cached) for r, toks, cached in items],
-            chunk=self.prefill_chunk)
-        rows = [i for i, x in enumerate(last_h) if x is not None]
-        if rows:
-            # pad to the batch bucket so head_logits compiles once per
-            # bucket (len(rows) varies per prefill and would retrace)
-            stack = [last_h[i] for i in rows]
-            stack += [stack[0]] * (_bucket(len(items)) - len(rows))
-            logits = M.head_logits(self.cfg, self.params, jnp.stack(stack))
-            arr = np.asarray(logits[:len(rows)], np.float32)
-            for j, i in enumerate(rows):
-                self.last_prefill_logits[items[i][0].rid] = arr[j]
+        # the logits fetch at the end waits for the prefill's device work
+        with obs.span("backend.prefill", rows=len(items), tokens=[
+                (cached, len(toks) - cached) for _, toks, cached in items]):
+            last_h = paged_prefill_chunks(
+                self.cfg, self.params, self.cache,
+                [(r.gpu_blocks, toks, cached) for r, toks, cached in items],
+                chunk=self.prefill_chunk)
+            rows = [i for i, x in enumerate(last_h) if x is not None]
+            if rows:
+                # pad to the batch bucket so head_logits compiles once per
+                # bucket (len(rows) varies per prefill and would retrace)
+                stack = [last_h[i] for i in rows]
+                stack += [stack[0]] * (_bucket(len(items)) - len(rows))
+                logits = M.head_logits(self.cfg, self.params,
+                                       jnp.stack(stack))
+                arr = np.asarray(logits[:len(rows)], np.float32)
+                for j, i in enumerate(rows):
+                    self.last_prefill_logits[items[i][0].rid] = arr[j]
         for r, toks, _ in items:
             n_blocks = -(-len(toks) // bs) if toks else 0
             self._prefill_sig[r.rid] = tuple(r.gpu_blocks[:n_blocks])
@@ -314,11 +330,12 @@ class JaxBackend:
         if self.cfg.arch_type == "audio":
             batch["frames"] = jnp.zeros(
                 (1, self.cfg.encoder_frames, self.cfg.d_model))
-        _, cache = M.prefill(self.cfg, self.params, batch)
-        if "k" in cache:
-            # cache k: (L, 1, S, Hkv, D) -> write into the paged pool
-            self.cache.write_prefill(req.gpu_blocks, cache["k"][:, 0],
-                                     cache["v"][:, 0])
+        with obs.span("backend.prefill", rows=1, tokens=[(0, len(toks))]):
+            _, cache = M.prefill(self.cfg, self.params, batch)
+            if "k" in cache:
+                # cache k: (L, 1, S, Hkv, D) -> write into the paged pool
+                self.cache.write_prefill(req.gpu_blocks, cache["k"][:, 0],
+                                         cache["v"][:, 0])
         n_blocks = -(-len(toks) // self.block_tokens)
         self._prefill_sig[req.rid] = tuple(req.gpu_blocks[:n_blocks])
         self.cache_len[req.rid] = len(toks)
@@ -326,37 +343,46 @@ class JaxBackend:
     def _decode_batch(self, reqs):
         if self.cfg.arch_type in ("ssm", "audio"):
             return  # non-paged decode state handled by dense path in examples
-        bs = self.block_tokens
-        b = len(reqs)
-        bb = _bucket(b)
-        pb = _bucket(max(len(r.gpu_blocks) for r in reqs))
-        tables = np.zeros((bb, pb), np.int32)
-        positions = np.zeros((bb,), np.int32)
-        attn_lens = np.zeros((bb,), np.int32)
-        toks = np.zeros((bb,), np.int32)
-        # padded rows and full-capacity rows write into the scratch block
-        slots = np.full((bb,), self.cache.scratch_slot, np.int32)
-        wrote = np.zeros((b,), bool)
-        for i, r in enumerate(reqs):
-            blocks = r.gpu_blocks
-            tables[i, :len(blocks)] = blocks
-            cl = min(self.cache_len.get(r.rid, 0), len(blocks) * bs)
-            prev = self.generated.get(r.rid) or [t % self.cfg.vocab_size
-                                                 for t in r.prompt_tokens[-1:]]
-            toks[i] = prev[-1]
-            positions[i] = cl
-            slots[i] = self.cache.slot_of(blocks, cl)
-            wrote[i] = slots[i] != self.cache.scratch_slot
-            # when the allocated blocks are exactly full the new token's KV
-            # is dropped (scratch write) and it attends over the existing
-            # context only — never over another request's blocks
-            attn_lens[i] = cl + (1 if wrote[i] else 0)
-        logits, self.cache.k, self.cache.v = M.paged_decode_step(
-            self.cfg, self.params, self.cache.k, self.cache.v,
-            jnp.asarray(toks), jnp.asarray(tables), jnp.asarray(positions),
-            jnp.asarray(attn_lens), jnp.asarray(slots))
-        nxt = np.asarray(jnp.argmax(logits[:b], -1), np.int32)
-        for i, r in enumerate(reqs):
-            self.generated.setdefault(r.rid, []).append(int(nxt[i]))
-            if wrote[i]:
-                self.cache_len[r.rid] = int(positions[i]) + 1
+        with obs.span("backend.decode_step"):
+            bs = self.block_tokens
+            b = len(reqs)
+            bb = _bucket(b)
+            pb = _bucket(max(len(r.gpu_blocks) for r in reqs))
+            with obs.span("backend.decode_prep", rows=b, pages=pb) as sp:
+                tables = np.zeros((bb, pb), np.int32)
+                positions = np.zeros((bb,), np.int32)
+                attn_lens = np.zeros((bb,), np.int32)
+                toks = np.zeros((bb,), np.int32)
+                # padded and full-capacity rows write into the scratch block
+                slots = np.full((bb,), self.cache.scratch_slot, np.int32)
+                wrote = np.zeros((b,), bool)
+                for i, r in enumerate(reqs):
+                    blocks = r.gpu_blocks
+                    tables[i, :len(blocks)] = blocks
+                    cl = min(self.cache_len.get(r.rid, 0), len(blocks) * bs)
+                    prev = self.generated.get(r.rid) or [
+                        t % self.cfg.vocab_size for t in r.prompt_tokens[-1:]]
+                    toks[i] = prev[-1]
+                    positions[i] = cl
+                    slots[i] = self.cache.slot_of(blocks, cl)
+                    wrote[i] = slots[i] != self.cache.scratch_slot
+                    # when the allocated blocks are exactly full the new
+                    # token's KV is dropped (scratch write) and it attends
+                    # over the existing context only — never over another
+                    # request's blocks
+                    attn_lens[i] = cl + (1 if wrote[i] else 0)
+                sp.set(keys=attn_lens[:b].tolist())
+            logits, self.cache.k, self.cache.v = M.paged_decode_step(
+                self.cfg, self.params, self.cache.k, self.cache.v,
+                jnp.asarray(toks), jnp.asarray(tables), jnp.asarray(positions),
+                jnp.asarray(attn_lens), jnp.asarray(slots))
+            with obs.span("backend.decode_sync"):
+                nxt = np.asarray(jnp.argmax(logits[:b], -1), np.int32)
+            for i, r in enumerate(reqs):
+                gen = self.generated.get(r.rid)
+                if gen is None:
+                    gen = self.generated[r.rid] = []
+                    obs.event("backend.first_token", rid=r.rid)
+                gen.append(int(nxt[i]))
+                if wrote[i]:
+                    self.cache_len[r.rid] = int(positions[i]) + 1

@@ -58,6 +58,7 @@ from typing import Dict, List, Optional, Tuple
 
 import numpy as np
 
+from repro import obs
 from repro.core import block_pool as BP
 from repro.core.costmodel import PlatformModel
 from repro.core.forecast import Forecaster
@@ -337,7 +338,6 @@ class Engine:
                                          self._push, self.metrics)
         self.util_samples: List[Tuple[float, float, float]] = []
         self.app_latencies: List[float] = []
-        self.req_latencies: List[float] = []
         self.type_stats: Dict[str, AgentTypeStats] = {}
 
     # ------------------------------------------------------------------ events
@@ -1100,7 +1100,6 @@ class Engine:
         req.finish_time = self.clock
         if self.backend is not None:
             self.backend.invalidate(req.rid)   # prune per-request state
-        self.req_latencies.append(self.clock - req.arrival)
         # session turn boundary: runs BEFORE the pin release below, so the
         # session pin takes over the request's entries without a gap
         drop_path = None
@@ -1203,38 +1202,39 @@ class Engine:
 
     # ------------------------------------------------------------------- phases
     def schedule_step(self) -> PressureSnapshot:
-        # Phase 1: refresh metadata + pressure snapshot
-        stats = self._refresh_type_stats()
-        snap = self.snapshot()
+        with obs.span("engine.schedule"):
+            # Phase 1: refresh metadata + pressure snapshot
+            stats = self._refresh_type_stats()
+            snap = self.snapshot()
 
-        # Phase 2: spatial re-partition
-        if self.cfg.spatial_enabled:
-            self.spatial.update_reservations(self.clock, stats)
+            # Phase 2: spatial re-partition
+            if self.cfg.spatial_enabled:
+                self.spatial.update_reservations(self.clock, stats)
 
-        # Phase 3: temporal — host-cache hygiene first (frequency/TTL
-        # capacity policy ages scores and expires cold cached copies so
-        # offload plans never contend with dead inventory), then uploads,
-        # then offload evaluation. The sweep runs in every mode that can
-        # hold cached host copies (mooncake's reactive path included).
-        self.metrics["host_cache_expired"] += \
-            self.temporal.sweep_host_cache(self.clock)
-        if self.cfg.temporal_enabled:
-            self._phase_uploads(snap)
-            self._phase_offloads(snap)
-        elif self.cfg.reactive_offload:
-            self._reactive_offload(snap)
-            self._phase_uploads(snap, reactive=True)
+            # Phase 3: temporal — host-cache hygiene first (frequency/TTL
+            # capacity policy ages scores and expires cold cached copies so
+            # offload plans never contend with dead inventory), then uploads,
+            # then offload evaluation. The sweep runs in every mode that can
+            # hold cached host copies (mooncake's reactive path included).
+            self.metrics["host_cache_expired"] += \
+                self.temporal.sweep_host_cache(self.clock)
+            if self.cfg.temporal_enabled:
+                self._phase_uploads(snap)
+                self._phase_offloads(snap)
+            elif self.cfg.reactive_offload:
+                self._reactive_offload(snap)
+                self._phase_uploads(snap, reactive=True)
 
-        # Phase 4: admission
-        self._phase_admission(snap)
+            # Phase 4: admission
+            self._phase_admission(snap)
 
-        # Phase 5 (workflow-aware prefetch): speculative promotions run
-        # AFTER admission so demand work gets first claim on blocks and
-        # the stream this step; the prefetch targets agents of *future*
-        # steps and rides whatever budget is left over.
-        if self.cfg.host_promotion and self.temporal.cfg.prefetch:
-            self._phase_prefetch(snap)
-        return snap
+            # Phase 5 (workflow-aware prefetch): speculative promotions run
+            # AFTER admission so demand work gets first claim on blocks and
+            # the stream this step; the prefetch targets agents of *future*
+            # steps and rides whatever budget is left over.
+            if self.cfg.host_promotion and self.temporal.cfg.prefetch:
+                self._phase_prefetch(snap)
+            return snap
 
     def _phase_uploads(self, snap: PressureSnapshot, reactive=False):
         cands = [r for r in self.offloaded.values()
@@ -1433,6 +1433,8 @@ class Engine:
         self.waiting = [r for r in deferred if r.state == ReqState.WAITING]
         for r in admitted:
             self.running.append(r)
+            obs.event("engine.admit", rid=r.rid,
+                      cached=r.prefix_cached_tokens)
             if r.first_token_time is None:
                 r.first_token_time = self.clock
 
@@ -1454,18 +1456,21 @@ class Engine:
         past the device coverage as a promotion candidate (``m.promo``) —
         promoted entries live in the device tier afterwards, so the tree
         is matched even when the vLLM-style device cache is off."""
-        m = PrefixMatch()
-        if (self.cfg.prefix_cache or self.cfg.host_promotion
-                or self.cfg.remote_pull or self.cfg.sessions):
-            m = self.prefix_store.match(
-                req.prompt_tokens,
-                promote=(self.cfg.host_promotion or self.cfg.remote_pull
-                         or self.cfg.sessions))
-        if self.cfg.cpu_prefix_cache and req.generated_total == 0:
-            # carried on the match, counted only when admission commits —
-            # a deferred request must not re-count its hit every retry
-            host_n = self.prefix_store.host_match(req.prompt_tokens)
-            m.cpu_hits = max(host_n - m.n_full, 0)
+        with obs.span("engine.prefix_match", rid=req.rid) as sp:
+            m = PrefixMatch()
+            if (self.cfg.prefix_cache or self.cfg.host_promotion
+                    or self.cfg.remote_pull or self.cfg.sessions):
+                m = self.prefix_store.match(
+                    req.prompt_tokens,
+                    promote=(self.cfg.host_promotion or self.cfg.remote_pull
+                             or self.cfg.sessions))
+            if self.cfg.cpu_prefix_cache and req.generated_total == 0:
+                # carried on the match, counted only when admission
+                # commits — a deferred request must not re-count its hit
+                # every retry
+                host_n = self.prefix_store.host_match(req.prompt_tokens)
+                m.cpu_hits = max(host_n - m.n_full, 0)
+            sp.set(tokens=m.tokens)
         return m
 
     def _claim_prefix(self, req: Request, m: PrefixMatch):
@@ -1742,56 +1747,57 @@ class Engine:
 
     # --------------------------------------------------------------- main loop
     def _process_events_until(self, t: float) -> None:
-        while self.events and self.events[0][0] <= t:
-            when, _, kind, payload = heapq.heappop(self.events)
-            self.clock = max(self.clock, when)
-            if kind == "app_arrival":
-                self._spawn_ready_nodes(self.apps[payload])
-            elif kind == "ext_spawn":
-                self._spawn_external(*payload)
-            elif kind == "pull_start":
-                toks, start, k, link, tag = payload
-                got, _used = self.start_remote_pull(toks, start, k, link,
-                                                    tag=tag)
-                if got is None:
-                    # voided at booking time (pool pressure / local
-                    # coverage won the race) — the router still holds
-                    # source pins keyed by ``tag``; tell it to drop them
-                    self.outbox.append(("pull_done", tag, self.clock))
-            elif kind == "ext_finish":
-                app_id, nid = payload
-                app = self.apps[app_id]
-                app.finished_nodes.add(nid)
-                self._spawn_ready_nodes(app)
-                if (app.finish_time is None
-                        and len(app.finished_nodes)
-                        == len(app.graph.nodes)):
-                    app.finish_time = self.clock
-                    self.app_latencies.append(self.clock - app.arrival)
-            elif kind == "call_finish":
-                req = self._find(payload)
-                if req is not None:
-                    self.call_finish(req)
-            elif kind == "transfer_done":
-                tr = self.transfers.on_event(payload)
-                if tr is not None:
-                    self._transfer_done(tr)
-            elif kind == "session_ttl":
-                sid, gen = payload
-                sess = self.sessions.get(sid)
-                if (sess is not None and sess.generation == gen
-                        and sess.state not in ("active", "dropped")):
-                    self._session_drop(sess)
-                    self.session_metrics["session_expired"] += 1
-            elif kind == "session_warm":
-                self._session_warm(*payload)
-            elif kind == "callback":
-                # deferred external action on the virtual timeline (the
-                # serving front door schedules trace arrivals this way so
-                # admission — and its cache / backpressure decisions —
-                # happens at the arrival instant, mid-quantum under
-                # continuous batching, not at the next step boundary)
-                payload(self.clock)
+        with obs.span("engine.events"):
+            while self.events and self.events[0][0] <= t:
+                when, _, kind, payload = heapq.heappop(self.events)
+                self.clock = max(self.clock, when)
+                if kind == "app_arrival":
+                    self._spawn_ready_nodes(self.apps[payload])
+                elif kind == "ext_spawn":
+                    self._spawn_external(*payload)
+                elif kind == "pull_start":
+                    toks, start, k, link, tag = payload
+                    got, _used = self.start_remote_pull(toks, start, k, link,
+                                                        tag=tag)
+                    if got is None:
+                        # voided at booking time (pool pressure / local
+                        # coverage won the race) — the router still holds
+                        # source pins keyed by ``tag``; tell it to drop them
+                        self.outbox.append(("pull_done", tag, self.clock))
+                elif kind == "ext_finish":
+                    app_id, nid = payload
+                    app = self.apps[app_id]
+                    app.finished_nodes.add(nid)
+                    self._spawn_ready_nodes(app)
+                    if (app.finish_time is None
+                            and len(app.finished_nodes)
+                            == len(app.graph.nodes)):
+                        app.finish_time = self.clock
+                        self.app_latencies.append(self.clock - app.arrival)
+                elif kind == "call_finish":
+                    req = self._find(payload)
+                    if req is not None:
+                        self.call_finish(req)
+                elif kind == "transfer_done":
+                    tr = self.transfers.on_event(payload)
+                    if tr is not None:
+                        self._transfer_done(tr)
+                elif kind == "session_ttl":
+                    sid, gen = payload
+                    sess = self.sessions.get(sid)
+                    if (sess is not None and sess.generation == gen
+                            and sess.state not in ("active", "dropped")):
+                        self._session_drop(sess)
+                        self.session_metrics["session_expired"] += 1
+                elif kind == "session_warm":
+                    self._session_warm(*payload)
+                elif kind == "callback":
+                    # deferred external action on the virtual timeline (the
+                    # serving front door schedules trace arrivals this way so
+                    # admission — and its cache / backpressure decisions —
+                    # happens at the arrival instant, mid-quantum under
+                    # continuous batching, not at the next step boundary)
+                    payload(self.clock)
 
     def _transfer_done(self, tr: Transfer) -> None:
         """Completion dispatch for the unified transfer plane. Cancelled
@@ -1862,6 +1868,12 @@ class Engine:
         bit-identical, not merely equivalent. A False return is not
         final in a cluster: router-injected events (ext_spawn, pulls)
         revive the replica."""
+        with obs.span("engine.step"):
+            progressed = self._step()
+            obs.event("engine.kv_used", value=self.pools[0].usage)
+        return progressed
+
+    def _step(self) -> bool:
         self._process_events_until(self.clock)
         if not (self.running or self.waiting):
             if not self.events and not self.offloaded:

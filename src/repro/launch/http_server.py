@@ -57,6 +57,7 @@ import zlib
 from dataclasses import dataclass, field
 from typing import Callable, Dict, List, Optional
 
+from repro import obs
 from repro.core.engine import Engine, EngineConfig
 from repro.core.graph import AppGraph, FuncNode
 from repro.launch.response_cache import ResponseCache, request_key
@@ -180,12 +181,16 @@ class FrontDoor:
                    if g.status in ("queued", "running")
                    and g.gid != exclude)
 
-    def submit(self, payload: dict,
-               arrival: Optional[float] = None) -> GenRequest:
+    def next_gid(self) -> str:
+        return f"g{next(self._seq)}"
+
+    def submit(self, payload: dict, arrival: Optional[float] = None,
+               gid: Optional[str] = None) -> GenRequest:
         """Submit one generate request. ``arrival`` in the future (trace
         mode) defers the admission decision — cache lookup and the
         backpressure check happen when the virtual clock reaches it, not
-        at trace-build time."""
+        at trace-build time. ``gid``: one taken from :meth:`next_gid`
+        (the HTTP server stamps the request's receipt under it first)."""
         payload = dict(payload)
         toks = payload.get("prompt")
         if (not isinstance(toks, list) or not toks
@@ -194,75 +199,76 @@ class FrontDoor:
         payload["max_tokens"] = int(payload.get("max_tokens", 16))
         if payload["max_tokens"] < 1:
             raise ValueError("max_tokens must be >= 1")
-        if arrival is None or arrival <= self.engine.clock:
-            return self._admit(payload, self.engine.clock)
+        gid = gid or self.next_gid()
+        now = self.engine.clock
+        if arrival is None or arrival <= now:
+            gen = GenRequest(gid, payload, request_key(payload), now)
+            self.gens[gid] = gen
+            return self._admit(payload, now, gen)
         # trace mode: defer the admission decision to the arrival instant
         # via an engine-timeline callback — under continuous batching the
         # event fires *mid-quantum*, so the cache lookup, the 429 check
         # and the admission all happen at the true arrival time
-        gid = f"g{next(self._seq)}"
         gen = GenRequest(gid, payload, request_key(payload), arrival,
                          status="scheduled")
         self.gens[gid] = gen
         self.engine._push(arrival, "callback",
-                          lambda now: self._admit(payload, now, gen=gen))
+                          lambda now: self._admit(payload, now, gen))
         return gen
 
     def _admit(self, payload: dict, now: float,
-               gen: Optional[GenRequest] = None) -> GenRequest:
-        key = request_key(payload)
-        if gen is None:
-            gen = GenRequest(f"g{next(self._seq)}", payload, key, now)
-            self.gens[gen.gid] = gen
-        # tier 1: exact-match response cache — a hit never touches the
-        # engine (zero steps, zero blocks, zero stream time)
-        if self.cache is not None:
-            hit = self.cache.get(key)
-            if hit is not None:
-                self.metrics["cache_hits"] += 1
-                gen.status = "cached"
+               gen: GenRequest) -> GenRequest:
+        with obs.span("frontdoor.submit", gid=gen.gid) as sp:
+            key = gen.key
+            # tier 1: exact-match response cache — a hit never touches the
+            # engine (zero steps, zero blocks, zero stream time)
+            if self.cache is not None:
+                hit = self.cache.get(key)
+                if hit is not None:
+                    self.metrics["cache_hits"] += 1
+                    gen.status = "cached"
+                    gen.finish = now
+                    gen.n_tokens = len(hit["tokens"])
+                    gen.result = dict(hit, cached=True, id=gen.gid)
+                    if self.on_finish:
+                        self.on_finish(gen)
+                    return gen
+                self.metrics["cache_misses"] += 1
+            # tier 2: bounded accept queue (structured 429 on overflow)
+            depth = self._pending_depth(exclude=gen.gid)
+            if depth >= self.max_pending:
+                self.metrics["rejected"] += 1
+                gen.status = "rejected"
                 gen.finish = now
-                gen.n_tokens = len(hit["tokens"])
-                gen.result = dict(hit, cached=True, id=gen.gid)
+                gen.result = {
+                    "ok": False, "op": "generate", "id": gen.gid,
+                    "error": f"backpressure: accept queue full "
+                             f"({depth} pending >= max_pending="
+                             f"{self.max_pending})",
+                    "queue_depth": depth, "status": 429,
+                }
                 if self.on_finish:
                     self.on_finish(gen)
                 return gen
-            self.metrics["cache_misses"] += 1
-        # tier 2: bounded accept queue (structured 429 on overflow)
-        depth = self._pending_depth(exclude=gen.gid)
-        if depth >= self.max_pending:
-            self.metrics["rejected"] += 1
-            gen.status = "rejected"
-            gen.finish = now
-            gen.result = {
-                "ok": False, "op": "generate", "id": gen.gid,
-                "error": f"backpressure: accept queue full "
-                         f"({depth} pending >= max_pending="
-                         f"{self.max_pending})",
-                "queue_depth": depth, "status": 429,
-            }
-            if self.on_finish:
-                self.on_finish(gen)
-            return gen
-        # tier 3: the engine — one single-agent app per generate call
-        g = AppGraph("gen")
-        g.add_agent("r", "http_gen", len(payload["prompt"]),
-                    decode_len=payload["max_tokens"])
-        gen.app_id = self.engine.submit_app(
-            g, now, prompt_tokens={0: list(payload["prompt"])})
-        gen.rid = f"{gen.app_id}/r"
-        # session turn: tie the request to its session so the engine's
-        # turn-end hook prices the KV pin. ``session_id`` stays in the
-        # payload, so it is part of the cache key — turns of different
-        # sessions never share a cached response. Planned tokens let the
-        # sim backend publish the full turn context at turn end.
-        sid = payload.get("session_id")
-        if sid is not None:
-            self.engine.session_track(
-                str(sid), gen.rid,
-                synth_tokens(gen.key, payload["max_tokens"]))
-        gen.status = "queued"
-        self.metrics["accepted"] += 1
+            # tier 3: the engine — one single-agent app per generate call
+            g = AppGraph("gen")
+            g.add_agent("r", "http_gen", len(payload["prompt"]),
+                        decode_len=payload["max_tokens"])
+            gen.app_id = self.engine.submit_app(
+                g, now, prompt_tokens={0: list(payload["prompt"])})
+            gen.rid = sp.rid = f"{gen.app_id}/r"
+            # session turn: tie the request to its session so the engine's
+            # turn-end hook prices the KV pin. ``session_id`` stays in the
+            # payload, so it is part of the cache key — turns of different
+            # sessions never share a cached response. Planned tokens let the
+            # sim backend publish the full turn context at turn end.
+            sid = payload.get("session_id")
+            if sid is not None:
+                self.engine.session_track(
+                    str(sid), gen.rid,
+                    synth_tokens(gen.key, payload["max_tokens"]))
+            gen.status = "queued"
+            self.metrics["accepted"] += 1
         return gen
 
     # ------------------------------------------------------------------ poll
@@ -279,34 +285,37 @@ class FrontDoor:
         step by whichever driver owns the loop. Iterates a snapshot:
         an ``on_finish`` hook may submit follow-up work (turn chaining)
         mid-sweep."""
-        for gen in list(self.gens.values()):
-            if gen.done or gen.status == "scheduled":
-                continue
-            app = self.engine.apps.get(gen.app_id)
-            req = app.node_request.get(0) if app is not None else None
-            if req is None:
-                continue
-            gen.status = "running" if gen.status == "queued" else gen.status
-            if gen.first_token is None and req.first_token_time is not None:
-                gen.first_token = req.first_token_time
-            if req.generated_total > gen.n_tokens:
-                gen.n_tokens = req.generated_total
-                if self.on_progress:
-                    self.on_progress(gen, gen.n_tokens)
-            if app.finish_time is not None:
-                gen.status = "finished"
-                gen.finish = app.finish_time
-                toks = self._tokens_of(gen, gen.n_tokens)
-                gen.result = {"ok": True, "id": gen.gid, "rid": gen.rid,
-                              "tokens": toks, "n_tokens": len(toks),
-                              "cached": False}
-                self.metrics["completed"] += 1
-                if self.cache is not None:
-                    self.cache.put(gen.key, {"ok": True, "rid": gen.rid,
-                                             "tokens": toks,
-                                             "n_tokens": len(toks)})
-                if self.on_finish:
-                    self.on_finish(gen)
+        with obs.span("frontdoor.poll"):
+            for gen in list(self.gens.values()):
+                if gen.done or gen.status == "scheduled":
+                    continue
+                app = self.engine.apps.get(gen.app_id)
+                req = app.node_request.get(0) if app is not None else None
+                if req is None:
+                    continue
+                if gen.status == "queued":
+                    gen.status = "running"
+                if (gen.first_token is None
+                        and req.first_token_time is not None):
+                    gen.first_token = req.first_token_time
+                if req.generated_total > gen.n_tokens:
+                    gen.n_tokens = req.generated_total
+                    if self.on_progress:
+                        self.on_progress(gen, gen.n_tokens)
+                if app.finish_time is not None:
+                    gen.status = "finished"
+                    gen.finish = app.finish_time
+                    toks = self._tokens_of(gen, gen.n_tokens)
+                    gen.result = {"ok": True, "id": gen.gid, "rid": gen.rid,
+                                  "tokens": toks, "n_tokens": len(toks),
+                                  "cached": False}
+                    self.metrics["completed"] += 1
+                    if self.cache is not None:
+                        self.cache.put(gen.key, {"ok": True, "rid": gen.rid,
+                                                 "tokens": toks,
+                                                 "n_tokens": len(toks)})
+                    if self.on_finish:
+                        self.on_finish(gen)
 
     # ----------------------------------------------------------- trace drive
     def outstanding(self) -> int:
@@ -412,6 +421,8 @@ class HttpServer:
         self._loop: Optional[asyncio.AbstractEventLoop] = None
         self._thread: Optional[threading.Thread] = None
         self._pump_task: Optional[asyncio.Task] = None
+        # wall time from which /v1/report's ``serving.wall`` counts
+        self._t_start = time.monotonic()
         # the exception that ended the pump: the server stops, every
         # waiting client gets a 500, and the owner's thread re-raises it
         self.error: Optional[BaseException] = None
@@ -493,11 +504,12 @@ class HttpServer:
                 # inter-turn gaps age sessions (and the response cache)
                 # at wall speed in the live server
                 self._idle_anchor = (time.monotonic(), eng.clock)
-                try:
-                    await asyncio.wait_for(self._wake.wait(),
-                                           eng.events[0][0] - eng.clock)
-                except asyncio.TimeoutError:
-                    pass
+                with obs.span("frontdoor.park"):
+                    try:
+                        await asyncio.wait_for(self._wake.wait(),
+                                               eng.events[0][0] - eng.clock)
+                    except asyncio.TimeoutError:
+                        pass
                 self._wake.clear()
                 continue
             progressed = eng.step()
@@ -507,7 +519,8 @@ class HttpServer:
                 if eng._wall_gated():
                     continue    # future timers: timed park at loop top
                 self._idle_anchor = (time.monotonic(), self.engine.clock)
-                await self._wake.wait()
+                with obs.span("frontdoor.park"):
+                    await self._wake.wait()
                 self._wake.clear()
             else:
                 # yield so accept/handler coroutines interleave with the
@@ -667,8 +680,10 @@ class HttpServer:
             return
         stream = payload.pop("stream", params.get("stream") == "1")
         async_ = payload.pop("async", params.get("async") == "1")
+        gid = self.front.next_gid()
+        obs.event("frontdoor.recv", gid=gid)
         try:
-            gen = self.front.submit(payload)
+            gen = self.front.submit(payload, gid=gid)
         except ValueError as e:
             self._send(writer, 400, {"ok": False, "op": "generate",
                                      "error": str(e)})
@@ -718,12 +733,19 @@ class HttpServer:
             while True:
                 kind, item = await q.get()
                 if kind == "progress":
-                    toks = self.front._tokens_of(gen, item)
-                    if len(toks) > sent:
-                        writer.write(chunk({"id": gen.gid,
-                                            "tokens": toks[sent:],
-                                            "done": False}))
-                        sent = len(toks)
+                    # the span holds the synchronous write; the drain
+                    # below yields to the loop and stays outside it
+                    with obs.span("frontdoor.stream", rid=gen.rid):
+                        toks = self.front._tokens_of(gen, item)[sent:]
+                        if toks:
+                            writer.write(chunk({"id": gen.gid,
+                                                "tokens": toks,
+                                                "done": False}))
+                            if not sent:
+                                obs.event("frontdoor.first_chunk",
+                                          rid=gen.rid, gid=gen.gid)
+                    if toks:
+                        sent += len(toks)
                         await writer.drain()
                 elif kind == "error":
                     writer.write(chunk({"id": gen.gid, "done": True,
@@ -734,14 +756,18 @@ class HttpServer:
                     await writer.drain()
                     return
                 else:   # done
-                    toks = gen.result.get("tokens", [])
-                    writer.write(chunk({"id": gen.gid,
-                                        "tokens": toks[sent:],
-                                        "done": True,
-                                        "n_tokens": len(toks),
-                                        "ttft": gen.ttft(),
-                                        "latency": gen.latency()}))
-                    writer.write(b"0\r\n\r\n")
+                    with obs.span("frontdoor.stream", rid=gen.rid):
+                        toks = gen.result.get("tokens", [])
+                        writer.write(chunk({"id": gen.gid,
+                                            "tokens": toks[sent:],
+                                            "done": True,
+                                            "n_tokens": len(toks),
+                                            "ttft": gen.ttft(),
+                                            "latency": gen.latency()}))
+                        writer.write(b"0\r\n\r\n")
+                        if toks and not sent:
+                            obs.event("frontdoor.first_chunk",
+                                      rid=gen.rid, gid=gen.gid)
                     await writer.drain()
                     return
         finally:
@@ -751,6 +777,12 @@ class HttpServer:
     def report(self) -> dict:
         rep = self.mcp.report()
         rep["serving"] = self.front.report()
+        # wall seconds of the requests this server received, from the
+        # program's record (the fields above are virtual seconds)
+        rep["serving"]["wall"] = {
+            k: self.front._dist(v)
+            for k, v in obs.wall_latencies(since=self._t_start).items()}
+        rep["tracing"] = obs.stats()
         return rep
 
     async def start(self) -> None:
