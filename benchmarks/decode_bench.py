@@ -50,9 +50,9 @@ def eager_decode_step(cfg, params, cache, tokens, tables, lens,
             bid = tables[i, lens[i] // block_tokens]
             off = lens[i] % block_tokens
             cache.k = cache.k.at[l, bid, off].set(
-                k[i, 0].astype(cache.k.dtype))
+                k[i, 0].reshape(-1).astype(cache.k.dtype))
             cache.v = cache.v.at[l, bid, off].set(
-                v[i, 0].astype(cache.v.dtype))
+                v[i, 0].reshape(-1).astype(cache.v.dtype))
         out = cache.decode_attention(l, q[:, 0], tables, lens + 1)
         x = x + L.attn_out(lp, out[:, None])
         if "w1" in lp:

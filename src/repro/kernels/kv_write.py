@@ -5,7 +5,12 @@ pool. Doing that with per-request functional updates costs 2·L·B full-cache
 copies per token on an accelerator; this kernel scatters the whole batch in
 one pass. Each grid step owns one sequence: the scalar-prefetched *slot id*
 (``block_id * block_size + offset``) selects the destination page, the
-in-page offset is a dynamic row store inside the fetched block.
+in-page offset the row replaced inside the fetched block.
+
+The pool is written in place: the kernels take every layer's pool,
+(L, N, bs, Hkv*D) with each token row lane-dense, and the layer as a
+scalar-prefetch operand, so a page is addressed ``(layer, page)`` and the
+caller never slices the pool per layer.
 
 Slot convention: callers mask a write (padded batch row, or a sequence
 whose allocated blocks are exactly full) by pointing its slot at a scratch
@@ -28,36 +33,36 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 
-def _write_kernel(slots_ref, k_new_ref, v_new_ref, k_in_ref, v_in_ref,
-                  k_out_ref, v_out_ref, *, block_size: int):
+def _write_kernel(layer_ref, slots_ref, k_new_ref, v_new_ref, k_in_ref,
+                  v_in_ref, k_out_ref, v_out_ref, *, block_size: int):
     i = pl.program_id(0)
-    off = slots_ref[i] % block_size
-    # carry the page through (aliased in/out), then patch one row
-    k_out_ref[...] = k_in_ref[...]
-    v_out_ref[...] = v_in_ref[...]
-    k_out_ref[0, pl.ds(off, 1)] = k_new_ref[...].astype(k_out_ref.dtype)
-    v_out_ref[0, pl.ds(off, 1)] = v_new_ref[...].astype(v_out_ref.dtype)
+    rows = jax.lax.broadcasted_iota(jnp.int32, (block_size, 1), 0)
+    hit = rows == slots_ref[i] % block_size
+    # carry the page through (aliased in/out) with one row replaced
+    k_out_ref[...] = jnp.where(hit, k_new_ref[...].astype(k_out_ref.dtype),
+                               k_in_ref[...])
+    v_out_ref[...] = jnp.where(hit, v_new_ref[...].astype(v_out_ref.dtype),
+                               v_in_ref[...])
 
 
-def _write_kernel_flat(slots_ref, k_new_ref, v_new_ref, k_in_ref, v_in_ref,
-                       k_out_ref, v_out_ref, *, block_size: int, batch: int):
+def _write_kernel_flat(layer_ref, slots_ref, k_new_ref, v_new_ref, k_in_ref,
+                       v_in_ref, k_out_ref, v_out_ref):
     """Single-grid-step variant: the whole batch lands as ONE vectorized
-    scatter over the slot-flattened pool. Interpret mode (CPU validation)
-    pays O(full pool) per grid step / per dynamic ref store, so the
-    per-sequence grid is collapsed here; the gridded kernel remains the
-    TPU path."""
-    slots = slots_ref[...]
-    n, bs = k_in_ref.shape[0], k_in_ref.shape[1]
-    tail = k_in_ref.shape[2:]
-    k = k_in_ref[...].reshape(n * bs, *tail)
-    v = v_in_ref[...].reshape(n * bs, *tail)
-    k = k.at[slots].set(k_new_ref[...].astype(k.dtype))
-    v = v.at[slots].set(v_new_ref[...].astype(v.dtype))
+    scatter over the layer's slot-flattened pool. Interpret mode (CPU
+    validation) pays O(full pool) per grid step / per dynamic ref store,
+    so the per-sequence grid is collapsed here; the gridded kernel
+    remains the TPU path."""
+    layer, slots = layer_ref[0], slots_ref[...]
+    nl, n, bs, width = k_in_ref.shape
+    k = k_in_ref[...].reshape(nl, n * bs, width)
+    v = v_in_ref[...].reshape(nl, n * bs, width)
+    k = k.at[layer, slots].set(k_new_ref[...][:, 0].astype(k.dtype))
+    v = v.at[layer, slots].set(v_new_ref[...][:, 0].astype(v.dtype))
     k_out_ref[...] = k.reshape(k_in_ref.shape)
     v_out_ref[...] = v.reshape(v_in_ref.shape)
 
 
-def _chunk_kernel(wpages_ref, wstart_ref, wcount_ref,        # scalar prefetch
+def _chunk_kernel(layer_ref, wpages_ref, wstart_ref, wcount_ref,
                   k_new_ref, v_new_ref, k_in_ref, v_in_ref,
                   k_out_ref, v_out_ref, *, block_size: int):
     """Destination-page-gridded chunk write: grid = (batch, window page).
@@ -66,54 +71,58 @@ def _chunk_kernel(wpages_ref, wstart_ref, wcount_ref,        # scalar prefetch
     tokens land several rows in the same page, and a per-token grid would
     revisit that page across steps (write-back racing the next step's
     aliased prefetch). Here every live page appears exactly once; only
-    scratch padding pages repeat, and those steps are pure copies."""
+    scratch padding pages repeat, and those steps are pure copies.
+
+    Page row r takes chunk token ``pp*bs - wstart + r`` when that token is
+    valid: a one-hot (bs, C) matmul picks the rows (each output row sums
+    one token times 1 and zeros, so the copy is exact), and the page keeps
+    its own rows elsewhere."""
     b = pl.program_id(0)
     pp = pl.program_id(1)
-    s = wstart_ref[b]                  # first token's in-page offset
-    cnt = wcount_ref[b]                # valid tokens in this row's chunk
-    k_out_ref[...] = k_in_ref[...]
-    v_out_ref[...] = v_in_ref[...]
-    base = pp * block_size - s         # chunk index of this page's offset 0
+    c = k_new_ref.shape[0]
+    # chunk index of each page row
+    j = (pp * block_size - wstart_ref[b]
+         + jax.lax.broadcasted_iota(jnp.int32, (block_size, c), 0))
+    pick = j == jax.lax.broadcasted_iota(jnp.int32, (block_size, c), 1)
+    live = (j[:, :1] >= 0) & (j[:, :1] < wcount_ref[b])     # (bs, 1)
 
-    def body(off, _):
-        j = base + off
+    def land(new_ref, in_ref, out_ref):
+        rows = jax.lax.dot_general(
+            pick.astype(jnp.float32), new_ref[...].astype(jnp.float32),
+            (((1,), (0,)), ((), ())), precision=jax.lax.Precision.HIGHEST,
+            preferred_element_type=jnp.float32)
+        out_ref[...] = jnp.where(live, rows.astype(out_ref.dtype),
+                                 in_ref[...])
 
-        @pl.when((j >= 0) & (j < cnt))
-        def _write():
-            k_out_ref[0, pl.ds(off, 1)] = \
-                k_new_ref[0, pl.ds(j, 1)].astype(k_out_ref.dtype)
-            v_out_ref[0, pl.ds(off, 1)] = \
-                v_new_ref[0, pl.ds(j, 1)].astype(v_out_ref.dtype)
-        return 0
-
-    jax.lax.fori_loop(0, block_size, body, 0)
+    land(k_new_ref, k_in_ref, k_out_ref)
+    land(v_new_ref, v_in_ref, v_out_ref)
 
 
-def _chunk_kernel_flat(wpages_ref, wstart_ref, wcount_ref, k_new_ref,
-                       v_new_ref, k_in_ref, v_in_ref, k_out_ref, v_out_ref,
-                       *, block_size: int, scratch_slot: int):
+def _chunk_kernel_flat(layer_ref, wpages_ref, wstart_ref, wcount_ref,
+                       k_new_ref, v_new_ref, k_in_ref, v_in_ref, k_out_ref,
+                       v_out_ref, *, block_size: int, scratch_slot: int):
     """Single-grid-step variant: reconstruct per-token slots from the page
     windows and land the whole chunk as one vectorized scatter (interpret
     mode pays O(full pool) per grid step). Invalid tokens (chunk/batch
     padding) point at the scratch slot; live slots are distinct."""
     bs = block_size
+    layer = layer_ref[0]
     wpages = wpages_ref[...]                       # (B, PP)
     wstart = wstart_ref[...]                       # (B,)
     wcount = wcount_ref[...]                       # (B,)
-    bsz, c = k_new_ref.shape[0], k_new_ref.shape[1]
+    bsz, c, width = k_new_ref.shape
     j = jax.lax.broadcasted_iota(jnp.int32, (bsz, c), 1)
     pos = wstart[:, None] + j                      # offset within the window
     pages = jnp.take_along_axis(wpages, pos // bs, axis=1)
     slots = jnp.where(j < wcount[:, None],
                       pages * bs + pos % bs, scratch_slot).reshape(-1)
-    n = k_in_ref.shape[0]
-    tail = k_in_ref.shape[2:]
-    k = k_in_ref[...].reshape(n * bs, *tail)
-    v = v_in_ref[...].reshape(n * bs, *tail)
-    kn = k_new_ref[...].reshape(bsz * c, *tail)
-    vn = v_new_ref[...].reshape(bsz * c, *tail)
-    k = k.at[slots].set(kn.astype(k.dtype))
-    v = v.at[slots].set(vn.astype(v.dtype))
+    nl, n = k_in_ref.shape[:2]
+    k = k_in_ref[...].reshape(nl, n * bs, width)
+    v = v_in_ref[...].reshape(nl, n * bs, width)
+    kn = k_new_ref[...].reshape(bsz * c, width)
+    vn = v_new_ref[...].reshape(bsz * c, width)
+    k = k.at[layer, slots].set(kn.astype(k.dtype))
+    v = v.at[layer, slots].set(vn.astype(v.dtype))
     k_out_ref[...] = k.reshape(k_in_ref.shape)
     v_out_ref[...] = v.reshape(v_in_ref.shape)
 
@@ -219,102 +228,109 @@ def kv_block_dequant(q, scales, out_dtype=jnp.float32,
 
 
 def kv_chunk_write(k_pages, v_pages, k_new, v_new, wpages, wstart, wcount,
-                   *, interpret: bool = True, flat: bool = None):
-    """Scatter one suffix chunk per sequence into the paged KV pool.
+                   layer, *, interpret: bool = True, flat: bool = None):
+    """Scatter one suffix chunk per sequence into layer ``layer`` of the
+    paged KV pool.
 
-    k_pages/v_pages: (N, bs, Hkv, D) — one layer's pool (incl. scratch, the
-                     last page, which also pads ``wpages``)
+    k_pages/v_pages: (L, N, bs, Hkv*D) — every layer's pool (incl. scratch,
+                     the last page, which also pads ``wpages``)
     k_new/v_new:     (B, C, Hkv, D)  — the batch's chunk K/V
     wpages:          (B, PP) int32   — destination pages of each row's
                      write window, in order (scratch-padded)
     wstart:          (B,) int32      — in-page offset of the row's first
                      token inside wpages[:, 0]
     wcount:          (B,) int32      — valid tokens per row (0 = padded row)
+    layer:           (1,) int32      — the layer written; every other
+                     layer is left as it was
     returns: (k_pages, v_pages) updated (aliased in place when compiled).
 
     ``flat`` selects the single-grid-step kernel; defaults to the
     interpret setting. The gridded path is TPU-safe for multi-token-per-
     page writes (unlike a per-token grid — see ``_chunk_kernel``).
     """
-    n, bs, hkv, d = k_pages.shape
+    nl, n, bs, width = k_pages.shape
     b, c = k_new.shape[0], k_new.shape[1]
+    k_new = k_new.reshape(b, c, width)
+    v_new = v_new.reshape(b, c, width)
     pp = wpages.shape[1]
     if flat is None:
         flat = interpret
+    out_shape = [jax.ShapeDtypeStruct(k_pages.shape, k_pages.dtype),
+                 jax.ShapeDtypeStruct(v_pages.shape, v_pages.dtype)]
 
     if flat:
         kernel = functools.partial(_chunk_kernel_flat, block_size=bs,
                                    scratch_slot=(n - 1) * bs)
         return pl.pallas_call(
-            kernel,
-            out_shape=[jax.ShapeDtypeStruct(k_pages.shape, k_pages.dtype),
-                       jax.ShapeDtypeStruct(v_pages.shape, v_pages.dtype)],
-            input_output_aliases={5: 0, 6: 1},
+            kernel, out_shape=out_shape,
+            input_output_aliases={6: 0, 7: 1},
             interpret=interpret,
-        )(wpages, wstart, wcount, k_new, v_new, k_pages, v_pages)
+        )(layer, wpages, wstart, wcount, k_new, v_new, k_pages, v_pages)
 
     kernel = functools.partial(_chunk_kernel, block_size=bs)
-    page_spec = pl.BlockSpec((1, bs, hkv, d),
-                             lambda b_, p_, wp, ws, wc: (wp[b_, p_], 0, 0, 0))
-    new_spec = pl.BlockSpec((1, c, hkv, d),
-                            lambda b_, p_, wp, ws, wc: (b_, 0, 0, 0))
+    page_spec = pl.BlockSpec(
+        (None, None, bs, width),
+        lambda b_, p_, ly, wp, ws, wc: (ly[0], wp[b_, p_], 0, 0))
+    new_spec = pl.BlockSpec((None, c, width),
+                            lambda b_, p_, ly, wp, ws, wc: (b_, 0, 0))
     return pl.pallas_call(
         kernel,
         grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=3,
+            num_scalar_prefetch=4,
             grid=(b, pp),
             in_specs=[new_spec, new_spec, page_spec, page_spec],
             out_specs=[page_spec, page_spec],
         ),
-        out_shape=[jax.ShapeDtypeStruct(k_pages.shape, k_pages.dtype),
-                   jax.ShapeDtypeStruct(v_pages.shape, v_pages.dtype)],
-        input_output_aliases={5: 0, 6: 1},
+        out_shape=out_shape,
+        input_output_aliases={6: 0, 7: 1},
         interpret=interpret,
-    )(wpages, wstart, wcount, k_new, v_new, k_pages, v_pages)
+    )(layer, wpages, wstart, wcount, k_new, v_new, k_pages, v_pages)
 
 
-def kv_token_write(k_pages, v_pages, k_new, v_new, slots,
+def kv_token_write(k_pages, v_pages, k_new, v_new, slots, layer,
                    *, interpret: bool = True, flat: bool = None):
-    """Scatter one new token per sequence into the paged KV pool.
+    """Scatter one new token per sequence into layer ``layer`` of the
+    paged KV pool.
 
-    k_pages/v_pages: (N, bs, Hkv, D) — one layer's pool
+    k_pages/v_pages: (L, N, bs, Hkv*D) — every layer's pool
     k_new/v_new:     (B, Hkv, D)     — the batch's new-token K/V
     slots:           (B,) int32      — absolute slot ids (block*bs + offset)
+    layer:           (1,) int32      — the layer written; every other
+                     layer is left as it was
     returns: (k_pages, v_pages) updated (aliased in place when compiled).
 
     ``flat`` selects the single-grid-step kernel (in-kernel write loop);
     defaults to the interpret setting.
     """
-    n, bs, hkv, d = k_pages.shape
+    nl, n, bs, width = k_pages.shape
     b = k_new.shape[0]
+    k_new = k_new.reshape(b, 1, width)
+    v_new = v_new.reshape(b, 1, width)
     if flat is None:
         flat = interpret
+    out_shape = [jax.ShapeDtypeStruct(k_pages.shape, k_pages.dtype),
+                 jax.ShapeDtypeStruct(v_pages.shape, v_pages.dtype)]
 
     if flat:
-        kernel = functools.partial(_write_kernel_flat, block_size=bs,
-                                   batch=b)
         return pl.pallas_call(
-            kernel,
-            out_shape=[jax.ShapeDtypeStruct(k_pages.shape, k_pages.dtype),
-                       jax.ShapeDtypeStruct(v_pages.shape, v_pages.dtype)],
-            input_output_aliases={3: 0, 4: 1},
+            _write_kernel_flat, out_shape=out_shape,
+            input_output_aliases={4: 0, 5: 1},
             interpret=interpret,
-        )(slots, k_new, v_new, k_pages, v_pages)
+        )(layer, slots, k_new, v_new, k_pages, v_pages)
 
     kernel = functools.partial(_write_kernel, block_size=bs)
-    page_spec = pl.BlockSpec((1, bs, hkv, d),
-                             lambda i, s: (s[i] // bs, 0, 0, 0))
-    new_spec = pl.BlockSpec((1, hkv, d), lambda i, s: (i, 0, 0))
+    page_spec = pl.BlockSpec((None, None, bs, width),
+                             lambda i, ly, s: (ly[0], s[i] // bs, 0, 0))
+    new_spec = pl.BlockSpec((None, 1, width), lambda i, ly, s: (i, 0, 0))
     return pl.pallas_call(
         kernel,
         grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=1,
+            num_scalar_prefetch=2,
             grid=(b,),
             in_specs=[new_spec, new_spec, page_spec, page_spec],
             out_specs=[page_spec, page_spec],
         ),
-        out_shape=[jax.ShapeDtypeStruct(k_pages.shape, k_pages.dtype),
-                   jax.ShapeDtypeStruct(v_pages.shape, v_pages.dtype)],
-        input_output_aliases={3: 0, 4: 1},
+        out_shape=out_shape,
+        input_output_aliases={4: 0, 5: 1},
         interpret=interpret,
-    )(slots, k_new, v_new, k_pages, v_pages)
+    )(layer, slots, k_new, v_new, k_pages, v_pages)

@@ -32,18 +32,20 @@ def _interpret() -> bool:
     raise RuntimeError(f"no Pallas kernel path for backend {backend!r}")
 
 
-@functools.partial(jax.jit, static_argnames=())
-def paged_attention(q, k_pages, v_pages, block_tables, context_lens):
-    """Decode attention over the paged KV pool. See kernel docstring."""
+@jax.jit
+def paged_attention(q, k_pages, v_pages, block_tables, context_lens, layer):
+    """Decode attention over layer ``layer`` of the paged KV pool. See
+    kernel docstring."""
     return _pa.paged_attention(q, k_pages, v_pages, block_tables,
-                               context_lens, interpret=_interpret())
+                               context_lens, layer, interpret=_interpret())
 
 
 @jax.jit
-def paged_prefill_attention(q, k_pages, v_pages, block_tables, q_pos):
-    """Chunked suffix-prefill attention over the paged KV pool."""
+def paged_prefill_attention(q, k_pages, v_pages, block_tables, q_pos, layer):
+    """Chunked suffix-prefill attention over layer ``layer`` of the paged
+    KV pool."""
     return _pp.paged_prefill_attention(q, k_pages, v_pages, block_tables,
-                                       q_pos, interpret=_interpret())
+                                       q_pos, layer, interpret=_interpret())
 
 
 @jax.jit
@@ -101,10 +103,10 @@ def kv_block_dequant(q, scales, out_dtype=jnp.float32):
     return _kw.kv_block_dequant(q, scales, out_dtype, interpret=_interpret())
 
 
-@jax.jit
-def block_gather_quant_layers(pools, indices):
+@functools.partial(jax.jit, static_argnames=("kv_heads",))
+def block_gather_quant_layers(pools, indices, kv_heads: int):
     """Fused all-layer gather + int8 quantize (quantize-on-offload)."""
-    return _bc.block_gather_quant_layers(pools, indices,
+    return _bc.block_gather_quant_layers(pools, indices, kv_heads,
                                          interpret=_interpret())
 
 
@@ -116,21 +118,23 @@ def block_scatter_dequant_layers(pools, indices, staging, scales):
 
 
 @jax.jit
-def kv_token_write(k_pages, v_pages, k_new, v_new, slots):
-    """Batched one-token-per-sequence KV write into the paged pool."""
-    return _kw.kv_token_write(k_pages, v_pages, k_new, v_new, slots,
+def kv_token_write(k_pages, v_pages, k_new, v_new, slots, layer):
+    """Batched one-token-per-sequence KV write into layer ``layer`` of the
+    paged pool, in place."""
+    return _kw.kv_token_write(k_pages, v_pages, k_new, v_new, slots, layer,
                               interpret=_interpret())
 
 
 @jax.jit
-def kv_chunk_write(k_pages, v_pages, k_new, v_new, wpages, wstart, wcount):
-    """Suffix-chunk KV scatter (prefill write path). Gridded per
-    destination page on TPU — a chunk lands several tokens in the same
-    page, so a per-token grid would revisit aliased output pages across
-    steps; here each live page is one grid step. Flat one-shot scatter
-    under the CPU interpreter."""
+def kv_chunk_write(k_pages, v_pages, k_new, v_new, wpages, wstart, wcount,
+                   layer):
+    """Suffix-chunk KV scatter (prefill write path) into layer ``layer``,
+    in place. Gridded per destination page on TPU — a chunk lands several
+    tokens in the same page, so a per-token grid would revisit aliased
+    output pages across steps; here each live page is one grid step. Flat
+    one-shot scatter under the CPU interpreter."""
     return _kw.kv_chunk_write(k_pages, v_pages, k_new, v_new, wpages,
-                              wstart, wcount, interpret=_interpret())
+                              wstart, wcount, layer, interpret=_interpret())
 
 
 @functools.partial(jax.jit, static_argnames=("chunk",))

@@ -11,16 +11,34 @@ import jax
 import jax.numpy as jnp
 
 
-def paged_attention_ref(q, k_pages, v_pages, block_tables, context_lens):
-    """Decode attention over a paged KV pool.
+def layer_pages(pools, layer, head_dim: int):
+    """One layer of a served pool, (L, N, bs, Hkv*D) -> (N, bs, Hkv, D):
+    each lane-dense row split into its kv heads."""
+    nl, n, bs, width = pools.shape
+    return pools[layer[0]].reshape(n, bs, width // head_dim, head_dim)
 
-    q:            (B, H, D)       — one query token per sequence
-    k_pages:      (N, bs, Hkv, D) — global block pool
-    v_pages:      (N, bs, Hkv, D)
-    block_tables: (B, P) int32    — page ids per sequence (padded arbitrary)
-    context_lens: (B,)   int32    — valid tokens per sequence
+
+def paged_attention_ref(q, k_pages, v_pages, block_tables, context_lens,
+                        layer):
+    """Decode attention over layer ``layer`` of a paged KV pool.
+
+    q:            (B, H, D)             — one query token per sequence
+    k_pages:      (L, N, bs, Hkv*D)     — every layer's block pool
+    v_pages:      (L, N, bs, Hkv*D)
+    block_tables: (B, P) int32          — page ids per sequence (padded
+                                          arbitrary)
+    context_lens: (B,)   int32          — valid tokens per sequence
+    layer:        (1,)   int32
     returns:      (B, H, D)
     """
+    d = q.shape[-1]
+    return _paged_attention_pages(q, layer_pages(k_pages, layer, d),
+                                  layer_pages(v_pages, layer, d),
+                                  block_tables, context_lens)
+
+
+def _paged_attention_pages(q, k_pages, v_pages, block_tables, context_lens):
+    """Decode attention over one layer's (N, bs, Hkv, D) pages."""
     b, h, d = q.shape
     n, bs, hkv, _ = k_pages.shape
     p = block_tables.shape[1]
@@ -41,17 +59,33 @@ def paged_attention_ref(q, k_pages, v_pages, block_tables, context_lens):
     return out.reshape(b, h, d).astype(q.dtype)
 
 
-def paged_prefill_attention_ref(q, k_pages, v_pages, block_tables, q_pos):
-    """Chunked paged-prefill attention over a paged KV pool.
+def paged_prefill_attention_ref(q, k_pages, v_pages, block_tables, q_pos,
+                                layer):
+    """Chunked paged-prefill attention over layer ``layer`` of a paged
+    KV pool.
 
-    q:            (B, C, H, D)    — one suffix chunk of queries per sequence
-    k_pages:      (N, bs, Hkv, D) — global block pool (prefix + suffix KV)
-    v_pages:      (N, bs, Hkv, D)
-    block_tables: (B, P) int32    — page ids per sequence (padded arbitrary)
-    q_pos:        (B, C) int32    — absolute position per query; -1 = padded
-                                    (fully masked, output row is zeros)
+    q:            (B, C, H, D)      — one suffix chunk of queries per
+                                      sequence
+    k_pages:      (L, N, bs, Hkv*D) — every layer's block pool (prefix +
+                                      suffix KV)
+    v_pages:      (L, N, bs, Hkv*D)
+    block_tables: (B, P) int32      — page ids per sequence (padded
+                                      arbitrary)
+    q_pos:        (B, C) int32      — absolute position per query; -1 =
+                                      padded (fully masked, output row is
+                                      zeros)
+    layer:        (1,) int32
     returns:      (B, C, H, D)
     """
+    d = q.shape[-1]
+    return _paged_prefill_attention_pages(
+        q, layer_pages(k_pages, layer, d), layer_pages(v_pages, layer, d),
+        block_tables, q_pos)
+
+
+def _paged_prefill_attention_pages(q, k_pages, v_pages, block_tables,
+                                   q_pos):
+    """Chunked prefill attention over one layer's (N, bs, Hkv, D) pages."""
     b, c, h, d = q.shape
     n, bs, hkv, _ = k_pages.shape
     p = block_tables.shape[1]
@@ -107,7 +141,7 @@ def paged_attention_quant_ref(q, k_pages, v_pages, k_scale, v_scale,
     """
     k = dequantize_block_ref(k_pages, k_scale)
     v = dequantize_block_ref(v_pages, v_scale)
-    return paged_attention_ref(q, k, v, block_tables, context_lens)
+    return _paged_attention_pages(q, k, v, block_tables, context_lens)
 
 
 def paged_prefill_attention_quant_ref(q, k_pages, v_pages, k_scale,
@@ -115,25 +149,29 @@ def paged_prefill_attention_quant_ref(q, k_pages, v_pages, k_scale,
     """Chunked prefill attention over int8 pools (dequant-then-oracle)."""
     k = dequantize_block_ref(k_pages, k_scale)
     v = dequantize_block_ref(v_pages, v_scale)
-    return paged_prefill_attention_ref(q, k, v, block_tables, q_pos)
+    return _paged_prefill_attention_pages(q, k, v, block_tables, q_pos)
 
 
-def block_gather_quant_layers_ref(pools, indices):
-    """Fused gather+quantize oracle. pools: (L, N, bs, Hkv, D) float;
-    indices: (M,) -> (int8 (L, M, bs, Hkv, D), scales (L, M, Hkv))."""
-    return quantize_block_ref(pools[:, indices])
+def block_gather_quant_layers_ref(pools, indices, kv_heads: int):
+    """Fused gather+quantize oracle. pools: (L, N, bs, Hkv*D) float;
+    indices: (M,) -> (int8 (L, M, bs, Hkv*D), scales (L, M, Hkv))."""
+    x = pools[:, indices]
+    q, scales = quantize_block_ref(x.reshape(*x.shape[:-1], kv_heads, -1))
+    return q.reshape(x.shape), scales
 
 
 def block_scatter_dequant_layers_ref(pools, indices, staging, scales):
-    """Fused dequantize+scatter oracle (promotion delivery path)."""
-    x = dequantize_block_ref(staging, scales, pools.dtype)
-    return pools.at[:, indices].set(x)
+    """Fused dequantize+scatter oracle (promotion delivery path):
+    staging (L, M, bs, Hkv*D) int8, scales (L, M, Hkv)."""
+    heads = staging.reshape(*staging.shape[:-1], scales.shape[-1], -1)
+    x = dequantize_block_ref(heads, scales, pools.dtype)
+    return pools.at[:, indices].set(x.reshape(staging.shape))
 
 
 def block_gather_ref(pages, indices):
     """Gather pool blocks into a contiguous staging buffer.
 
-    pages:   (N, bs, Hkv, D);  indices: (M,) int32  ->  (M, bs, Hkv, D)
+    pages:   (N, *page);  indices: (M,) int32  ->  (M, *page)
     """
     return pages[indices]
 
@@ -144,43 +182,49 @@ def block_scatter_ref(pages, indices, staging):
 
 
 def block_gather_layers_ref(pools, indices):
-    """All-layer gather. pools: (L, N, bs, Hkv, D); indices: (M,)."""
+    """All-layer gather. pools: (L, N, *page); indices: (M,)."""
     return pools[:, indices]
 
 
 def block_scatter_layers_ref(pools, indices, staging):
-    """All-layer scatter of staging (L, M, bs, Hkv, D) into pool blocks."""
+    """All-layer scatter of staging (L, M, *page) into pool blocks."""
     return pools.at[:, indices].set(staging)
 
 
-def kv_token_write_ref(k_pages, v_pages, k_new, v_new, slots):
-    """Batched decode-token write. Pools (N, bs, Hkv, D); new (B, Hkv, D);
-    slots (B,) absolute slot ids (block*bs + offset), distinct per batch."""
-    n, bs, hkv, d = k_pages.shape
-    kf = k_pages.reshape(n * bs, hkv, d)
-    vf = v_pages.reshape(n * bs, hkv, d)
-    kf = kf.at[slots].set(k_new.astype(k_pages.dtype))
-    vf = vf.at[slots].set(v_new.astype(v_pages.dtype))
+def kv_token_write_ref(k_pages, v_pages, k_new, v_new, slots, layer):
+    """Batched decode-token write into layer ``layer``. Pools
+    (L, N, bs, Hkv*D); new (B, Hkv, D); slots (B,) absolute slot ids
+    (block*bs + offset), distinct per batch."""
+    nl, n, bs, width = k_pages.shape
+    b = k_new.shape[0]
+    kf = k_pages.reshape(nl, n * bs, width)
+    vf = v_pages.reshape(nl, n * bs, width)
+    kf = kf.at[layer[0], slots].set(
+        k_new.reshape(b, width).astype(k_pages.dtype))
+    vf = vf.at[layer[0], slots].set(
+        v_new.reshape(b, width).astype(v_pages.dtype))
     return kf.reshape(k_pages.shape), vf.reshape(v_pages.shape)
 
 
 def kv_chunk_write_ref(k_pages, v_pages, k_new, v_new, wpages, wstart,
-                       wcount):
-    """Suffix-chunk write. Pools (N, bs, Hkv, D); new (B, C, Hkv, D);
-    wpages (B, PP) destination pages per row window (scratch = page N-1
-    padding); wstart (B,) in-page offset of the first token; wcount (B,)
-    valid tokens per row."""
-    n, bs, hkv, d = k_pages.shape
+                       wcount, layer):
+    """Suffix-chunk write into layer ``layer``. Pools (L, N, bs, Hkv*D);
+    new (B, C, Hkv, D); wpages (B, PP) destination pages per row window
+    (scratch = page N-1 padding); wstart (B,) in-page offset of the first
+    token; wcount (B,) valid tokens per row."""
+    nl, n, bs, width = k_pages.shape
     b, c = k_new.shape[0], k_new.shape[1]
     j = jnp.arange(c)[None, :]
     pos = wstart[:, None] + j
     pages = jnp.take_along_axis(wpages, pos // bs, axis=1)
     slots = jnp.where(j < wcount[:, None],
                       pages * bs + pos % bs, (n - 1) * bs).reshape(-1)
-    kf = k_pages.reshape(n * bs, hkv, d)
-    vf = v_pages.reshape(n * bs, hkv, d)
-    kf = kf.at[slots].set(k_new.reshape(b * c, hkv, d).astype(k_pages.dtype))
-    vf = vf.at[slots].set(v_new.reshape(b * c, hkv, d).astype(v_pages.dtype))
+    kf = k_pages.reshape(nl, n * bs, width)
+    vf = v_pages.reshape(nl, n * bs, width)
+    kf = kf.at[layer[0], slots].set(
+        k_new.reshape(b * c, width).astype(k_pages.dtype))
+    vf = vf.at[layer[0], slots].set(
+        v_new.reshape(b * c, width).astype(v_pages.dtype))
     return kf.reshape(k_pages.shape), vf.reshape(v_pages.shape)
 
 

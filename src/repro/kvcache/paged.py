@@ -1,11 +1,17 @@
 """Paged KV cache: device pools + host pool, driven by the block ids that
 ``repro.core.block_pool`` hands out.
 
-Layout (per model): k/v pools of shape (L, N+1, bs, Hkv, D). The Pallas
-kernels view a single layer (N+1, bs, Hkv, D); the migration data plane
-moves whole (L, bs, Hkv, D) block-columns per block id so one logical block
-id covers every layer (that matches vLLM's block granularity accounting
-with 3 MiB/block across all layers).
+Layout (per model): k/v pools of shape (L, N+1, bs, Hkv*D) — pages of
+``bs`` token rows, each row one token's kv heads side by side. The row is
+the minor axis, so XLA's default tiled layout is also the kernels' own
+and a multiple of 128 lanes needs no padding (stablelm-3b: 32 x 80 =
+2560 = 20 x 128). The step programs hand the kernels the whole pool and
+a layer index, and the kernels read and write ``(layer, page)`` in place:
+no per-layer slice, relayout or restack. The migration data plane moves
+whole (L, bs, Hkv*D) block-columns per block id so one logical block id
+covers every layer (that matches vLLM's block granularity accounting
+with 3 MiB/block across all layers). The host tier keeps the same
+layout, so a block moves between the tiers without a reshape.
 
 Row ``N`` (``scratch_block``) is never handed out by the allocator: it is
 the write sink for masked decode writes — padded batch rows, and sequences
@@ -36,7 +42,8 @@ class PagedKVCache:
         self.host_precision = host_precision
         nl, hkv, dh = cfg.num_layers, max(cfg.num_kv_heads, 1), \
             max(cfg.head_dim, 1)
-        shape = (nl, num_blocks + 1, block_size, hkv, dh)
+        self.kv_heads = hkv
+        shape = (nl, num_blocks + 1, block_size, hkv * dh)
         self.k = jnp.zeros(shape, dtype)
         self.v = jnp.zeros(shape, dtype)
         # host pool is numpy (pinned host memory stand-in); host_blocks=0
@@ -50,7 +57,7 @@ class PagedKVCache:
             # quantized host tier: int8 payload + per-(block, kv-head)
             # fp32 scales, half the fp16 bytes (the device pool keeps
             # ``dtype`` — precision changes only as blocks cool to host)
-            hshape = (nl, host_blocks, block_size, hkv, dh)
+            hshape = (nl, host_blocks, block_size, hkv * dh)
             self.host_k = np.zeros(hshape, np.int8)
             self.host_v = np.zeros(hshape, np.int8)
             self.host_scales_k = np.zeros((nl, host_blocks, hkv),
@@ -58,7 +65,7 @@ class PagedKVCache:
             self.host_scales_v = np.zeros((nl, host_blocks, hkv),
                                           np.float32)
         else:
-            hshape = (nl, host_blocks, block_size, hkv, dh)
+            hshape = (nl, host_blocks, block_size, hkv * dh)
             self.host_k = np.zeros(hshape, dtype)
             self.host_v = np.zeros(hshape, dtype)
 
@@ -109,8 +116,8 @@ class PagedKVCache:
         if pad:
             k_seq = jnp.pad(k_seq, ((0, 0), (0, pad), (0, 0), (0, 0)))
             v_seq = jnp.pad(v_seq, ((0, 0), (0, pad), (0, 0), (0, 0)))
-        kb = k_seq.reshape(k_seq.shape[0], n, bs, *k_seq.shape[2:])
-        vb = v_seq.reshape(v_seq.shape[0], n, bs, *v_seq.shape[2:])
+        kb = k_seq.reshape(k_seq.shape[0], n, bs, -1)
+        vb = v_seq.reshape(v_seq.shape[0], n, bs, -1)
         idx = jnp.asarray(blocks[:n], jnp.int32)
         self.k = ops.block_scatter_layers(self.k, idx,
                                           kb.astype(self.k.dtype))
@@ -121,19 +128,18 @@ class PagedKVCache:
         """Batched decode write: k_toks/v_toks (L, B, Hkv, D); slots (B,)
         absolute slot ids (scratch slot = masked). One scatter for every
         (layer, sequence) pair — no Python loop over L or B."""
-        nl = self.k.shape[0]
-        nb = self.k.shape[1]
-        bs = self.block_size
+        nl, nb, bs, width = self.k.shape
         slots = jnp.asarray(slots, jnp.int32)
         # fold layers into the page axis so one kernel call covers (L, B):
-        # layer l's block b lives at folded block l*(N+1)+b
-        kf = self.k.reshape(nl * nb, bs, *self.k.shape[3:])
-        vf = self.v.reshape(nl * nb, bs, *self.v.shape[3:])
+        # layer l's block b lives at folded block l*(N+1)+b of layer 0
+        kf = self.k.reshape(1, nl * nb, bs, width)
+        vf = self.v.reshape(1, nl * nb, bs, width)
         layer_base = (jnp.arange(nl, dtype=jnp.int32) * (nb * bs))[:, None]
         folded = (layer_base + slots[None, :]).reshape(-1)
-        kn = k_toks.reshape(-1, *k_toks.shape[2:])
-        vn = v_toks.reshape(-1, *v_toks.shape[2:])
-        kf, vf = ops.kv_token_write(kf, vf, kn, vn, folded)
+        kn = k_toks.reshape(-1, width)
+        vn = v_toks.reshape(-1, width)
+        kf, vf = ops.kv_token_write(kf, vf, kn, vn, folded,
+                                    jnp.zeros((1,), jnp.int32))
         self.k = kf.reshape(self.k.shape)
         self.v = vf.reshape(self.v.shape)
 
@@ -146,8 +152,10 @@ class PagedKVCache:
     def gather_seq(self, blocks: List[int], length: int):
         """Materialize one request's KV: (L, length, Hkv, D)."""
         idx = jnp.asarray(blocks, jnp.int32)
-        k = self.k[:, idx].reshape(self.k.shape[0], -1, *self.k.shape[3:])
-        v = self.v[:, idx].reshape(self.v.shape[0], -1, *self.v.shape[3:])
+        shape = (self.k.shape[0], -1, self.kv_heads,
+                 self.k.shape[3] // self.kv_heads)
+        k = self.k[:, idx].reshape(shape)
+        v = self.v[:, idx].reshape(shape)
         return k[:, :length], v[:, :length]
 
     def decode_attention(self, layer: int, q, block_tables, context_lens):
@@ -155,8 +163,9 @@ class PagedKVCache:
 
         q: (B, H, D); block_tables: (B, P) int32; context_lens: (B,).
         """
-        return ops.paged_attention(q, self.k[layer], self.v[layer],
-                                   block_tables, context_lens)
+        return ops.paged_attention(q, self.k, self.v, block_tables,
+                                   context_lens,
+                                   jnp.asarray([layer], jnp.int32))
 
     # ---- copy-on-write ------------------------------------------------------
     def copy_blocks(self, src: List[int], dst: List[int]):
@@ -179,8 +188,10 @@ class PagedKVCache:
         self._require_host("offload()")
         idx = jnp.asarray(gpu_blocks, jnp.int32)
         if self.host_precision == "int8_host":
-            kq, ks = ops.block_gather_quant_layers(self.k, idx)
-            vq, vs = ops.block_gather_quant_layers(self.v, idx)
+            kq, ks = ops.block_gather_quant_layers(self.k, idx,
+                                                   self.kv_heads)
+            vq, vs = ops.block_gather_quant_layers(self.v, idx,
+                                                   self.kv_heads)
             self.host_k[:, host_blocks] = np.asarray(kq)
             self.host_v[:, host_blocks] = np.asarray(vq)
             self.host_scales_k[:, host_blocks] = np.asarray(ks)

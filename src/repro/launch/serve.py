@@ -43,10 +43,14 @@ log = logging.getLogger("repro.serve")
 REPO_ROOT = Path(__file__).resolve().parents[3]
 
 # Device memory of a served model: the parameters, the K and V pools, and
-# the paged steps' temporaries, which hold about one more copy of both
-# pools (the compiler relays out each layer's pool slice around every
-# kernel call). A sixteenth of the device's limit stays free for
-# activations, logits and migration staging.
+# room for one more copy of both. Both are sized as when the pools were
+# laid out per head, (L, N+1, bs, Hkv, D), and the step programs relaid
+# each layer's slice out around every kernel call: the footprint of that
+# layout, which a TPU pads, and room for its relayouts. The pools are
+# lane-dense now and read in place, so neither is needed; the sizing stays
+# so the served pool, and the work the benchmark measures, stay the same
+# (a larger pool is a capacity change of its own). A sixteenth of the
+# device's limit stays free for activations, logits and migration staging.
 HBM_MARGIN = 1 / 16
 # The host tier holds every device block twice over, within this bound.
 HOST_TIER_BYTES = 8 << 30
@@ -80,7 +84,8 @@ def _block_bytes(cfg, block_tokens: int) -> int:
 
 def _pool_bytes(cfg, n_blocks: int, block_tokens: int) -> int:
     """Device bytes of one K or V pool of ``n_blocks`` plus the scratch
-    block, as the backend lays it out (a TPU pads tiled dimensions)."""
+    block in the per-head layout the pools are sized by (see above; a TPU
+    pads tiled dimensions)."""
     shape = (cfg.num_layers, n_blocks + 1, block_tokens, cfg.num_kv_heads,
              cfg.head_dim)
     zeros = jax.jit(lambda: jnp.zeros(shape, M._dtype(cfg)))
@@ -89,8 +94,8 @@ def _pool_bytes(cfg, n_blocks: int, block_tokens: int) -> int:
 
 def pool_blocks(cfg, block_tokens: int, limit: int) -> int:
     """Largest device pool whose K and V pools, twice over (resident plus
-    the steps' temporaries), fit in ``limit`` after the parameters and
-    the margin."""
+    the room above), fit in ``limit`` after the parameters and the
+    margin."""
     params = sum(x.size * x.dtype.itemsize
                  for x in jax.tree.leaves(M.param_specs(cfg)))
     budget = limit * (1 - HBM_MARGIN) - params

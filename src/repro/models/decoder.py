@@ -293,14 +293,17 @@ def paged_decode(cfg, stacked, x, k_pool, v_pool, tables, positions,
                  attn_lens, slots):
     """Single-token batched decode against the *paged* KV pool.
 
-    The serving hot path: one ``lax.scan`` over stacked layer params with
-    the per-layer pool slices riding along as scan inputs/outputs, so the
-    HLO is O(1) in depth and the whole step jits as one program. KV writes
-    go through the Pallas batched token-write kernel (no per-request loop)
-    and attention through the Pallas paged-attention kernel.
+    The serving hot path: one ``lax.scan`` over stacked layer params, so
+    the HLO is O(1) in depth and the whole step jits as one program. The
+    pools ride the scan's carry and each layer's kernels index them by
+    the layer number that rides along with the params: KV writes go
+    through the Pallas batched token-write kernel (no per-request loop)
+    and attention through the Pallas paged-attention kernel, both on the
+    whole pool in place — nothing slices, relayouts or restacks it per
+    layer.
 
     x:             (B, 1, d) embedded tokens
-    k_pool/v_pool: (L, N+1, bs, Hkv, D) paged pools (incl. scratch block)
+    k_pool/v_pool: (L, N+1, bs, Hkv*D) paged pools (incl. scratch block)
     tables:        (B, P) int32 block tables (padded rows arbitrary)
     positions:     (B,) int32 rope position of the new token (= cached len)
     attn_lens:     (B,) int32 tokens to attend over (incl. the new token
@@ -314,19 +317,23 @@ def paged_decode(cfg, stacked, x, k_pool, v_pool, tables, positions,
     pos = positions[:, None]                             # (B, 1)
     valid = (attn_lens > 0)[:, None]                     # (B, 1) real rows
 
-    def body(h, xs):
-        lp, kl, vl = xs
+    def body(carry, xs):
+        h, kp, vp = carry
+        lp, layer = xs
+        layer = layer[None]                              # (1,) for prefetch
         xn = L.rms_norm(h, lp["attn_norm"])
         q, k, v = L.qkv_project(cfg, lp, xn)             # (B, 1, ·, ·)
         q = L.apply_rope(q, pos, cfg.rope_theta)
         k = L.apply_rope(k, pos, cfg.rope_theta)
-        kl, vl = ops.kv_token_write(kl, vl, k[:, 0], v[:, 0], slots)
-        out = ops.paged_attention(q[:, 0], kl, vl, tables, attn_lens)
+        kp, vp = ops.kv_token_write(kp, vp, k[:, 0], v[:, 0], slots, layer)
+        out = ops.paged_attention(q[:, 0], kp, vp, tables, attn_lens, layer)
         h = h + L.attn_out(lp, out[:, None])
         h = _paged_ffn(cfg, lp, h, valid)
-        return h, (kl, vl)
+        return (h, kp, vp), None
 
-    h, (k_pool, v_pool) = stack_scan(body, x, (stacked, k_pool, v_pool))
+    layers = jnp.arange(k_pool.shape[0], dtype=jnp.int32)
+    (h, k_pool, v_pool), _ = stack_scan(body, (x, k_pool, v_pool),
+                                        (stacked, layers))
     return h, k_pool, v_pool
 
 
@@ -338,13 +345,13 @@ def paged_prefill(cfg, stacked, x, k_pool, v_pool, tables, q_pos,
     lives in pool blocks (via the prefix store); this computes and writes
     only the C uncached suffix tokens of the chunk, then attends each
     query over prefix + preceding suffix through the block table. Same
-    scan-over-stacked-params shape as ``paged_decode`` — per-layer pool
-    slices ride the scan, writes go through the Pallas chunk-write
-    (gridded per destination page), attention through the Pallas
-    paged-prefill kernel.
+    scan-over-stacked-params shape as ``paged_decode`` — the pools ride
+    the carry and are indexed in place by layer, writes go through the
+    Pallas chunk-write (gridded per destination page), attention through
+    the Pallas paged-prefill kernel.
 
     x:             (B, C, d) embedded suffix-chunk tokens
-    k_pool/v_pool: (L, N+1, bs, Hkv, D) paged pools (incl. scratch block)
+    k_pool/v_pool: (L, N+1, bs, Hkv*D) paged pools (incl. scratch block)
     tables:        (B, P) int32 block tables (cached prefix + own blocks)
     q_pos:         (B, C) int32 absolute position per query (-1 = padded;
                    padded queries are masked and never written)
@@ -359,19 +366,24 @@ def paged_prefill(cfg, stacked, x, k_pool, v_pool, tables, q_pos,
     pos = jnp.maximum(q_pos, 0)                          # rope positions
     valid = q_pos >= 0                                   # (B, C) real queries
 
-    def body(h, xs):
-        lp, kl, vl = xs
+    def body(carry, xs):
+        h, kp, vp = carry
+        lp, layer = xs
+        layer = layer[None]                              # (1,) for prefetch
         xn = L.rms_norm(h, lp["attn_norm"])
         q, k, v = L.qkv_project(cfg, lp, xn)             # (B, C, ·, ·)
         q = L.apply_rope(q, pos, cfg.rope_theta)
         k = L.apply_rope(k, pos, cfg.rope_theta)
-        kl, vl = ops.kv_chunk_write(kl, vl, k, v, wpages, wstart, wcount)
-        out = ops.paged_prefill_attention(q, kl, vl, tables, q_pos)
+        kp, vp = ops.kv_chunk_write(kp, vp, k, v, wpages, wstart, wcount,
+                                    layer)
+        out = ops.paged_prefill_attention(q, kp, vp, tables, q_pos, layer)
         h = h + L.attn_out(lp, out)
         h = _paged_ffn(cfg, lp, h, valid)
-        return h, (kl, vl)
+        return (h, kp, vp), None
 
-    h, (k_pool, v_pool) = stack_scan(body, x, (stacked, k_pool, v_pool))
+    layers = jnp.arange(k_pool.shape[0], dtype=jnp.int32)
+    (h, k_pool, v_pool), _ = stack_scan(body, (x, k_pool, v_pool),
+                                        (stacked, layers))
     return h, k_pool, v_pool
 
 

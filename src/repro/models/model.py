@@ -160,9 +160,10 @@ def paged_decode_step(cfg, params, k_pool, v_pool, tokens, tables,
     ``cfg`` is static (frozen dataclass), so one compilation is cached per
     (config, batch-bucket, table-bucket) shape — callers pad ``tokens``/
     ``tables``/``slots`` to bucketed shapes to keep the cache small. The
-    pools flow through the layer scan, so the write path is a Pallas
-    scatter per layer with no per-request Python anywhere. The pools are
-    DONATED: callers must rebind them from the return value.
+    pools, (L, N+1, bs, Hkv*D), ride the layer scan's carry and each
+    layer's Pallas kernels read and write them in place by layer index,
+    with no per-request Python anywhere. The pools are DONATED: callers
+    must rebind them from the return value.
 
     Returns (logits (B, V), k_pool, v_pool).
     """

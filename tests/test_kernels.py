@@ -1,4 +1,10 @@
-"""Pallas kernel sweeps vs pure-jnp oracles (interpret mode on CPU)."""
+"""Pallas kernel sweeps vs pure-jnp oracles (interpret mode on CPU).
+
+The served pool is every layer's pages, (L, N, bs, Hkv*D) with each token
+row lane-dense, and the decode/prefill kernels take it whole with a layer
+index. The sweeps use a layer other than 0 of a three-layer pool, so a
+kernel that reads or writes the wrong layer fails, and the write kernels
+must leave every other layer bit-identical."""
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -10,9 +16,26 @@ from repro.kernels import ref as R
 KEY = jax.random.PRNGKey(7)
 
 
+NL = 3                      # layers of the swept pools
+LAYER = jnp.asarray([1], jnp.int32)
+
+
 def tol(dtype):
     return dict(atol=2e-2, rtol=2e-2) if dtype == jnp.bfloat16 \
         else dict(atol=3e-5, rtol=3e-5)
+
+
+def pools(key, n, bs, hkv, d, dtype):
+    """A K and a V pool of NL layers in the served lane-dense layout."""
+    kk, kv = jax.random.split(key)
+    return (jax.random.normal(kk, (NL, n, bs, hkv * d), dtype),
+            jax.random.normal(kv, (NL, n, bs, hkv * d), dtype))
+
+
+def assert_other_layers_intact(out, before):
+    keep = [i for i in range(NL) if i != int(LAYER[0])]
+    np.testing.assert_array_equal(np.asarray(out)[keep],
+                                  np.asarray(before)[keep])
 
 
 @pytest.mark.parametrize("flat", [True, False],
@@ -23,18 +46,20 @@ def tol(dtype):
     (3, 8, 2, 64, 16, 5),     # GQA 4:1
     (2, 16, 1, 64, 32, 2),    # MQA
     (2, 5, 5, 16, 8, 4),      # odd head count (whisper-like)
+    (2, 10, 2, 80, 8, 3),     # GQA 5:1 at stablelm-3b's head size
+    (2, 10, 2, 128, 16, 3),   # GQA 5:1 at Qwen2.5-14B's head size
 ])
 def test_paged_attention(dtype, b, h, hkv, d, bs, p, flat):
     from repro.kernels.paged_attention import paged_attention
     n = p * b + 4
     ks = jax.random.split(KEY, 5)
     q = jax.random.normal(ks[0], (b, h, d), dtype)
-    kp = jax.random.normal(ks[1], (n, bs, hkv, d), dtype)
-    vp = jax.random.normal(ks[2], (n, bs, hkv, d), dtype)
+    kp, vp = pools(ks[1], n, bs, hkv, d, dtype)
     bt = jax.random.randint(ks[3], (b, p), 0, n)
     cl = jax.random.randint(ks[4], (b,), 1, p * bs + 1)
-    out = paged_attention(q, kp, vp, bt, cl, interpret=True, flat=flat)
-    ref = R.paged_attention_ref(q, kp, vp, bt, cl)
+    out = paged_attention(q, kp, vp, bt, cl, LAYER, interpret=True,
+                          flat=flat)
+    ref = R.paged_attention_ref(q, kp, vp, bt, cl, LAYER)
     np.testing.assert_allclose(np.asarray(out, np.float32),
                                np.asarray(ref, np.float32), **tol(dtype))
 
@@ -47,6 +72,8 @@ def test_paged_attention(dtype, b, h, hkv, d, bs, p, flat):
     (3, 8, 8, 2, 64, 16, 5),   # GQA 4:1
     (2, 16, 16, 1, 64, 32, 2),  # MQA, chunk spans whole pages
     (2, 5, 5, 5, 16, 8, 4),    # odd chunk + odd head count
+    (2, 4, 10, 2, 80, 8, 3),   # GQA 5:1 at stablelm-3b's head size
+    (1, 8, 10, 2, 128, 16, 3),  # GQA 5:1 at Qwen2.5-14B's head size
 ])
 def test_paged_prefill_attention(dtype, b, c, h, hkv, d, bs, p, flat):
     """Chunked suffix-prefill attention vs the dense oracle, including
@@ -56,13 +83,12 @@ def test_paged_prefill_attention(dtype, b, c, h, hkv, d, bs, p, flat):
     n = p * b + 4
     ks = jax.random.split(KEY, 5)
     q = jax.random.normal(ks[0], (b, c, h, d), dtype)
-    kp = jax.random.normal(ks[1], (n, bs, hkv, d), dtype)
-    vp = jax.random.normal(ks[2], (n, bs, hkv, d), dtype)
+    kp, vp = pools(ks[1], n, bs, hkv, d, dtype)
     bt = jax.random.randint(ks[3], (b, p), 0, n)
     qpos = jax.random.randint(ks[4], (b, c), -1, p * bs)
-    out = paged_prefill_attention(q, kp, vp, bt, qpos,
+    out = paged_prefill_attention(q, kp, vp, bt, qpos, LAYER,
                                   interpret=True, flat=flat)
-    ref = R.paged_prefill_attention_ref(q, kp, vp, bt, qpos)
+    ref = R.paged_prefill_attention_ref(q, kp, vp, bt, qpos, LAYER)
     np.testing.assert_allclose(np.asarray(out, np.float32),
                                np.asarray(ref, np.float32), **tol(dtype))
     # padded queries produce exact zeros
@@ -80,12 +106,13 @@ def test_paged_prefill_matches_decode_convention():
     b, h, hkv, d, bs, p = 2, 4, 2, 32, 8, 3
     ks = jax.random.split(KEY, 4)
     q = jax.random.normal(ks[0], (b, 1, h, d), jnp.float32)
-    kp = jax.random.normal(ks[1], (p * b + 2, bs, hkv, d), jnp.float32)
-    vp = jax.random.normal(ks[2], (p * b + 2, bs, hkv, d), jnp.float32)
+    kp, vp = pools(ks[1], p * b + 2, bs, hkv, d, jnp.float32)
     bt = jax.random.randint(ks[3], (b, p), 0, p * b + 2)
     ctx = jnp.asarray([5, 17], jnp.int32)
-    pre = paged_prefill_attention(q, kp, vp, bt, ctx[:, None], interpret=True)
-    dec = paged_attention(q[:, 0], kp, vp, bt, ctx + 1, interpret=True)
+    pre = paged_prefill_attention(q, kp, vp, bt, ctx[:, None], LAYER,
+                                  interpret=True)
+    dec = paged_attention(q[:, 0], kp, vp, bt, ctx + 1, LAYER,
+                          interpret=True)
     np.testing.assert_allclose(np.asarray(pre[:, 0]), np.asarray(dec),
                                atol=3e-5, rtol=3e-5)
 
@@ -108,41 +135,46 @@ def test_block_gather_scatter(dtype, m):
 @pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
 @pytest.mark.parametrize("m", [1, 3, 5])
 def test_block_gather_scatter_layers(dtype, m):
-    """All-layer migration kernels match the per-layer refs."""
-    pools = jax.random.normal(KEY, (3, 10, 8, 2, 16), dtype)
+    """All-layer migration kernels match the per-layer refs, on the
+    served pool's lane-dense pages."""
+    kp, _ = pools(KEY, 10, 8, 2, 16, dtype)
     idx = jnp.asarray(np.random.default_rng(1).choice(10, m, replace=False),
                       jnp.int32)
-    stg = ops.block_gather_layers(pools, idx)
+    stg = ops.block_gather_layers(kp, idx)
     np.testing.assert_array_equal(
-        np.asarray(stg), np.asarray(R.block_gather_layers_ref(pools, idx)))
-    new = jax.random.normal(jax.random.PRNGKey(2), (3, m, 8, 2, 16), dtype)
-    out = ops.block_scatter_layers(pools, idx, new)
+        np.asarray(stg), np.asarray(R.block_gather_layers_ref(kp, idx)))
+    new = jax.random.normal(jax.random.PRNGKey(2), (NL, m, 8, 32), dtype)
+    out = ops.block_scatter_layers(kp, idx, new)
     np.testing.assert_array_equal(
         np.asarray(out),
-        np.asarray(R.block_scatter_layers_ref(pools, idx, new)))
+        np.asarray(R.block_scatter_layers_ref(kp, idx, new)))
 
 
 @pytest.mark.parametrize("flat", [True, False],
                          ids=["flat(cpu)", "grid(tpu)"])
 @pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
 @pytest.mark.parametrize("b", [1, 4, 7])
-def test_kv_token_write(dtype, b, flat):
-    """Batched decode-token scatter matches the functional ref."""
+@pytest.mark.parametrize("hkv,d", [(2, 16), (2, 80), (1, 128)])
+def test_kv_token_write(dtype, b, flat, hkv, d):
+    """Batched decode-token scatter matches the functional ref, in the
+    written layer only."""
     from repro.kernels.kv_write import kv_token_write
-    n, bs, hkv, d = 12, 8, 2, 16
+    n, bs = 12, 8
     ks = jax.random.split(KEY, 4)
-    kp = jax.random.normal(ks[0], (n, bs, hkv, d), dtype)
-    vp = jax.random.normal(ks[1], (n, bs, hkv, d), dtype)
+    kp, vp = pools(ks[0], n, bs, hkv, d, dtype)
     kn = jax.random.normal(ks[2], (b, hkv, d), dtype)
     vn = jax.random.normal(ks[3], (b, hkv, d), dtype)
     rng = np.random.default_rng(4)
     blocks = rng.choice(n, b, replace=False)        # distinct blocks
     offs = rng.integers(0, bs, b)
     slots = jnp.asarray(blocks * bs + offs, jnp.int32)
-    ko, vo = kv_token_write(kp, vp, kn, vn, slots, interpret=True, flat=flat)
-    kr, vr = R.kv_token_write_ref(kp, vp, kn, vn, slots)
+    ko, vo = kv_token_write(kp, vp, kn, vn, slots, LAYER, interpret=True,
+                            flat=flat)
+    kr, vr = R.kv_token_write_ref(kp, vp, kn, vn, slots, LAYER)
     np.testing.assert_array_equal(np.asarray(ko), np.asarray(kr))
     np.testing.assert_array_equal(np.asarray(vo), np.asarray(vr))
+    assert_other_layers_intact(ko, kp)
+    assert_other_layers_intact(vo, vp)
 
 
 @pytest.mark.parametrize("flat", [True, False],
@@ -150,18 +182,18 @@ def test_kv_token_write(dtype, b, flat):
 @pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
 @pytest.mark.parametrize("b,c,start", [(1, 4, 2), (3, 8, 0), (2, 6, 7),
                                        (2, 8, 3)])
-def test_kv_chunk_write(dtype, b, c, start, flat):
+@pytest.mark.parametrize("hkv,d", [(2, 16), (2, 80), (1, 128)])
+def test_kv_chunk_write(dtype, b, c, start, flat, hkv, d):
     """Suffix-chunk scatter (prefill write path) matches the functional
     ref: windows starting mid-page, spilling across page boundaries, with
     per-row valid counts and padded rows (wcount=0) never writing. The
     gridded variant owns one destination page per step, so live pages are
     never revisited (the TPU aliasing hazard a per-token grid would have)."""
     from repro.kernels.kv_write import kv_chunk_write
-    n, bs, hkv, d = 12, 8, 2, 16
+    n, bs = 12, 8
     pp = (c - 1) // bs + 2
     ks = jax.random.split(KEY, 4)
-    kp = jax.random.normal(ks[0], (n, bs, hkv, d), dtype)
-    vp = jax.random.normal(ks[1], (n, bs, hkv, d), dtype)
+    kp, vp = pools(ks[0], n, bs, hkv, d, dtype)
     kn = jax.random.normal(ks[2], (b, c, hkv, d), dtype)
     vn = jax.random.normal(ks[3], (b, c, hkv, d), dtype)
     rng = np.random.default_rng(6)
@@ -175,42 +207,47 @@ def test_kv_chunk_write(dtype, b, c, start, flat):
         wpages[i, :npages] = [free.pop() for _ in range(npages)]
     wstart = np.full((b,), start, np.int32)
     ko, vo = kv_chunk_write(kp, vp, kn, vn, jnp.asarray(wpages),
-                            jnp.asarray(wstart), jnp.asarray(wcount),
+                            jnp.asarray(wstart), jnp.asarray(wcount), LAYER,
                             interpret=True, flat=flat)
     kr, vr = R.kv_chunk_write_ref(kp, vp, kn, vn, jnp.asarray(wpages),
-                                  jnp.asarray(wstart), jnp.asarray(wcount))
+                                  jnp.asarray(wstart), jnp.asarray(wcount),
+                                  LAYER)
     # scratch (page n-1) holds dead content and the variants differ there
     # by design (scatter vs skip); every live page must match exactly
-    np.testing.assert_array_equal(np.asarray(ko)[:-1], np.asarray(kr)[:-1])
-    np.testing.assert_array_equal(np.asarray(vo)[:-1], np.asarray(vr)[:-1])
-    # untouched pages (outside every window, except scratch) are intact
+    np.testing.assert_array_equal(np.asarray(ko)[:, :-1],
+                                  np.asarray(kr)[:, :-1])
+    np.testing.assert_array_equal(np.asarray(vo)[:, :-1],
+                                  np.asarray(vr)[:, :-1])
+    # untouched pages (outside every window, except scratch) are intact,
+    # and so is every other layer, scratch included
     touched = set(wpages.reshape(-1).tolist()) | {n - 1}
     keep = np.array([p for p in range(n) if p not in touched], int)
     if keep.size:
-        np.testing.assert_array_equal(np.asarray(ko)[keep],
-                                      np.asarray(kp)[keep])
+        np.testing.assert_array_equal(np.asarray(ko)[:, keep],
+                                      np.asarray(kp)[:, keep])
+    assert_other_layers_intact(ko, kp)
+    assert_other_layers_intact(vo, vp)
 
 
 def test_kv_token_write_scratch_collisions_leave_live_blocks_alone():
     """Masked rows all share one scratch block; live blocks stay intact."""
     n, bs, hkv, d = 6, 4, 2, 8
     ks = jax.random.split(KEY, 4)
-    kp = jax.random.normal(ks[0], (n, bs, hkv, d), jnp.float32)
-    vp = jax.random.normal(ks[1], (n, bs, hkv, d), jnp.float32)
+    kp, vp = pools(ks[0], n, bs, hkv, d, jnp.float32)
     kn = jax.random.normal(ks[2], (3, hkv, d), jnp.float32)
     vn = jax.random.normal(ks[3], (3, hkv, d), jnp.float32)
     scratch = (n - 1) * bs                          # block 5 = scratch
     slots = jnp.asarray([2 * bs + 1, scratch, scratch], jnp.int32)
-    ko, vo = ops.kv_token_write(kp, vp, kn, vn, slots)
+    ko, vo = ops.kv_token_write(kp, vp, kn, vn, slots, LAYER)
+    ko, vo, kp, vp = (np.asarray(x)[1] for x in (ko, vo, kp, vp))
     # the live write landed
-    np.testing.assert_array_equal(np.asarray(ko[2, 1]), np.asarray(kn[0]))
+    np.testing.assert_array_equal(ko[2, 1], np.asarray(kn[0]).reshape(-1))
     # every block except the written one and scratch is untouched
     keep = np.array([0, 1, 3, 4])
-    np.testing.assert_array_equal(np.asarray(ko)[keep], np.asarray(kp)[keep])
-    np.testing.assert_array_equal(np.asarray(vo)[keep], np.asarray(vp)[keep])
-    np.testing.assert_array_equal(np.asarray(ko[2, 0]), np.asarray(kp[2, 0]))
-    np.testing.assert_array_equal(np.asarray(ko[2, 2:]),
-                                  np.asarray(kp[2, 2:]))
+    np.testing.assert_array_equal(ko[keep], kp[keep])
+    np.testing.assert_array_equal(vo[keep], vp[keep])
+    np.testing.assert_array_equal(ko[2, 0], kp[2, 0])
+    np.testing.assert_array_equal(ko[2, 2:], kp[2, 2:])
 
 
 def test_migration_roundtrip_bit_exact():

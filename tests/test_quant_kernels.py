@@ -31,6 +31,15 @@ from repro.kvcache.paged import PagedKVCache
 
 KEY = jax.random.PRNGKey(21)
 
+
+def one_layer(pages):
+    """(N, bs, Hkv, D) pages as layer 0 of a served lane-dense pool, for
+    the full-precision oracles, which read the served layout."""
+    return pages.reshape(1, *pages.shape[:2], -1)
+
+
+LAYER0 = jnp.zeros((1,), jnp.int32)
+
 # quantized-pool attention vs full-precision attention: int8 round-trip
 # error is <= scale/2 per element; softmax mixing keeps the output error
 # the same order (scales here are ~4/127 for unit-normal inputs)
@@ -89,13 +98,20 @@ def test_dequant_respects_out_dtype():
 # fused migration kernels (quantize-on-offload / dequantize-on-upload)
 # ---------------------------------------------------------------------------
 
+def lane_bound(scales, d, eps):
+    """Per-element round-trip bound of lane-dense rows: each kv head's
+    scale/2 over its D lanes. scales (L, M, Hkv) -> (L, M, 1, Hkv*D)."""
+    return np.repeat(np.asarray(scales), d, axis=-1)[:, :, None, :] / 2 \
+        + eps
+
+
 def test_block_gather_quant_layers_matches_oracle():
     nl, n, bs, hkv, d = 2, 10, 8, 2, 32
     ks = jax.random.split(KEY, 2)
-    pools = jax.random.normal(ks[0], (nl, n, bs, hkv, d), jnp.float32)
+    pools = jax.random.normal(ks[0], (nl, n, bs, hkv * d), jnp.float32)
     idx = jnp.asarray([7, 2, 5], jnp.int32)
-    q, s = ops.block_gather_quant_layers(pools, idx)
-    q_ref, s_ref = R.block_gather_quant_layers_ref(pools, idx)
+    q, s = ops.block_gather_quant_layers(pools, idx, hkv)
+    q_ref, s_ref = R.block_gather_quant_layers_ref(pools, idx, hkv)
     np.testing.assert_array_equal(np.asarray(q), np.asarray(q_ref))
     np.testing.assert_allclose(np.asarray(s), np.asarray(s_ref),
                                atol=0, rtol=1e-6)
@@ -104,9 +120,10 @@ def test_block_gather_quant_layers_matches_oracle():
 def test_block_scatter_dequant_layers_matches_oracle():
     nl, n, bs, hkv, d = 2, 10, 8, 2, 32
     ks = jax.random.split(KEY, 3)
-    pools = jax.random.normal(ks[0], (nl, n, bs, hkv, d), jnp.float32)
+    pools = jax.random.normal(ks[0], (nl, n, bs, hkv * d), jnp.float32)
     src = jax.random.normal(ks[1], (nl, 3, bs, hkv, d), jnp.float32)
     staging, scales = R.quantize_block_ref(src)
+    staging = staging.reshape(nl, 3, bs, hkv * d)
     idx = jnp.asarray([1, 8, 4], jnp.int32)
     got = ops.block_scatter_dequant_layers(pools, idx, staging, scales)
     ref = R.block_scatter_dequant_layers_ref(pools, idx, staging, scales)
@@ -120,12 +137,12 @@ def test_block_scatter_dequant_layers_matches_oracle():
 
 def test_gather_scatter_roundtrip_within_half_scale():
     nl, n, bs, hkv, d = 2, 8, 8, 2, 16
-    pools = jax.random.normal(KEY, (nl, n, bs, hkv, d), jnp.float32)
+    pools = jax.random.normal(KEY, (nl, n, bs, hkv * d), jnp.float32)
     idx = jnp.asarray([0, 3, 6], jnp.int32)
-    q, s = ops.block_gather_quant_layers(pools, idx)
+    q, s = ops.block_gather_quant_layers(pools, idx, hkv)
     back = ops.block_scatter_dequant_layers(pools, idx, q, s)
     err = np.abs(np.asarray(back[:, idx]) - np.asarray(pools[:, idx]))
-    bound = np.asarray(s)[:, :, None, :, None] / 2 + 1e-7
+    bound = lane_bound(s, d, 1e-7)
     assert np.all(err <= bound)
 
 
@@ -158,7 +175,8 @@ def test_paged_attention_quant(b, h, hkv, d, bs, p, flat):
     np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
                                atol=3e-5, rtol=3e-5)
     # ... and within the logits tolerance of full-precision attention
-    full = R.paged_attention_ref(q, kp, vp, bt, cl)
+    full = R.paged_attention_ref(q, one_layer(kp), one_layer(vp), bt,
+                                 cl, LAYER0)
     np.testing.assert_allclose(np.asarray(out), np.asarray(full),
                                **LOGITS_TOL)
 
@@ -187,7 +205,9 @@ def test_paged_prefill_attention_quant(b, c, h, hkv, d, bs, p, flat):
                                               bt, qpos)
     np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
                                atol=3e-5, rtol=3e-5)
-    full = R.paged_prefill_attention_ref(q, kp, vp, bt, qpos)
+    full = R.paged_prefill_attention_ref(q, one_layer(kp),
+                                         one_layer(vp), bt, qpos,
+                                         LAYER0)
     np.testing.assert_allclose(np.asarray(out), np.asarray(full),
                                **LOGITS_TOL)
     dead = np.asarray(qpos) < 0
@@ -221,10 +241,9 @@ def test_paged_cache_int8_offload_upload_roundtrip():
     cache.upload([0, 1, 2], [6, 7, 0])
     back_k = np.asarray(cache.k[:, [6, 7, 0]])
     back_v = np.asarray(cache.v[:, [6, 7, 0]])
-    bound_k = np.asarray(cache.host_scales_k[:, :3])[
-        :, :, None, :, None] / 2 + 1e-7
-    bound_v = np.asarray(cache.host_scales_v[:, :3])[
-        :, :, None, :, None] / 2 + 1e-7
+    d = MCFG.head_dim
+    bound_k = lane_bound(cache.host_scales_k[:, :3], d, 1e-7)
+    bound_v = lane_bound(cache.host_scales_v[:, :3], d, 1e-7)
     assert np.all(np.abs(back_k - orig_k) <= bound_k)
     assert np.all(np.abs(back_v - orig_v) <= bound_v)
 
@@ -312,8 +331,8 @@ def test_backend_decode_survives_int8_offload_promote_cycle():
     r.gpu_blocks_by_device[0] = [10, 11, 12]
     r.reserved_upload_blocks = []
     back_k = np.asarray(backend.cache.k[:, jnp.asarray([10, 11, 12])])
-    bound = np.asarray(backend.cache.host_scales_k[:, :3])[
-        :, :, None, :, None] / 2 + 1e-6
+    bound = lane_bound(backend.cache.host_scales_k[:, :3], MCFG.head_dim,
+                       1e-6)
     assert np.all(np.abs(back_k - snap_k) <= bound)
     for _ in range(steps_after):
         backend.decode([r])
